@@ -71,6 +71,7 @@ from repro.config.base import RippleConfig
 from repro.core.collapse import collapsed_attention
 from repro.core.policy import (ReuseDecision, ReusePolicy, RippleStats,
                                get_policy, list_policies, register_policy)
+from repro.utils.platform import platform
 
 __all__ = [
     "attention_dispatch", "autotune_attention", "DispatchPlan",
@@ -302,7 +303,7 @@ def _store_disk(key: str, entry: dict, path: Optional[str] = None):
 def autotune_key(backend: str, n_bucket: int, d: int, dv: int) -> str:
     # Keyed by platform: block sizes tuned on a CPU interpret run must
     # never steer the TPU kernel (and vice versa).
-    return f"{_platform()}:{backend}:n{n_bucket}:d{d}:dv{dv}"
+    return f"{platform()}:{backend}:n{n_bucket}:d{d}:dv{dv}"
 
 
 def autotune_attention(q, k, v, *, backend: str = "pallas",
@@ -347,7 +348,7 @@ def autotune_attention(q, k, v, *, backend: str = "pallas",
                         "us": round(time_best(make(bq, bk), repeats) * 1e6,
                                     1)})
     best = min(results, key=lambda r: r["us"])
-    entry = {**best, "device": _platform(), "candidates": results}
+    entry = {**best, "device": platform(), "candidates": results}
     _store_disk(key, entry, cache_path)
     _PLAN_CACHE.clear()  # plans may now resolve to the tuned blocks
     return entry
@@ -377,10 +378,6 @@ def _tuned_blocks(backend: str, n: int, d: int, dv: int):
 # ---------------------------------------------------------------------------
 # Plan resolution
 # ---------------------------------------------------------------------------
-
-
-def _platform() -> str:
-    return jax.devices()[0].platform
 
 
 def resolve_backend(cfg: RippleConfig, backend: Optional[str], *,
@@ -425,7 +422,7 @@ def resolve_backend(cfg: RippleConfig, backend: Optional[str], *,
         # stance as the other kernels) so mask policies never silently
         # lose their structural savings to a dense fallback.
         return "sparse"
-    pallas_ok = (_platform() == "tpu" and not has_bias and not emits_bias
+    pallas_ok = (platform() == "tpu" and not has_bias and not emits_bias
                  and cfg.window == 2 and n_tokens % 2 == 0)
     if pallas_ok:
         return "pallas"
@@ -442,7 +439,7 @@ def _fused_requested(cfg: RippleConfig) -> bool:
     # 'auto': the fused kernel wins on TPU; in interpret mode on CPU it
     # is correctness-representative but slower than the fused-by-XLA
     # host path, so it stays off there.
-    return _platform() == "tpu"
+    return platform() == "tpu"
 
 
 def _resolve_seq_sharding(mesh: Optional[Mesh], q_shape, resolved: str,

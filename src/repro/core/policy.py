@@ -63,6 +63,13 @@ class RippleStats:
     k_snap_frac: jax.Array
 
 
+jax.tree_util.register_dataclass(
+    RippleStats,
+    data_fields=["savings", "structural_savings", "q_snap_frac",
+                 "k_snap_frac"],
+    meta_fields=[])
+
+
 @dataclasses.dataclass
 class ReuseDecision:
     """What one policy decided for one attention call.

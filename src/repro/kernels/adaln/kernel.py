@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _adaln_kernel(x_ref, shift_ref, scale_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)  # (bt, d)
@@ -54,7 +52,7 @@ def adaln_modulate_kernel(x: jax.Array, shift: jax.Array, scale: jax.Array,
         ],
         out_specs=pl.BlockSpec((None, block_t, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, N, d), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -8,10 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.adaln.kernel import adaln_modulate_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
+from repro.utils.platform import on_tpu
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_t", "interpret"))
@@ -19,7 +16,7 @@ def adaln_modulate(x, shift, scale, *, eps: float = 1e-6, block_t: int = 256,
                    interpret: bool | None = None):
     """x: (B, N, d); shift/scale: (B, d)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     B, N, d = x.shape
     bt = min(block_t, N)
     Np = -(-N // bt) * bt

@@ -14,12 +14,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash.kernel import flash_attention as _kernel
+from repro.utils.platform import on_tpu
 
 _NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
 
 
 def _pad_to(x, target, axis):
@@ -36,7 +33,7 @@ def flash_attention(q, k, v, *, block_q: int = 128, block_k: int = 128,
                     interpret: bool | None = None):
     """q,k,v: (B, H, N, d) -> (B, H, N, dv)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     B, H, Nq, d = q.shape
     Nk = k.shape[2]
     dv = v.shape[3]

@@ -36,7 +36,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+
+def _below(delta, theta):
+    """Δ < θ, compared in f32: the v5e VPU has no bf16 compare.  θ
+    arrives already rounded to Δ's dtype, and both sides widen exactly,
+    so the verdict equals a compare in Δ's own dtype."""
+    return delta.astype(jnp.float32) < theta
 
 
 def _reuse_kernel(theta_ref, x_e_ref, x_o_ref, out_o_ref, mask_o_ref):
@@ -44,14 +49,15 @@ def _reuse_kernel(theta_ref, x_e_ref, x_o_ref, out_o_ref, mask_o_ref):
     x_e = x_e_ref[...]
     x_o = x_o_ref[...]
     delta = jnp.abs(x_o - x_e) * 0.5
-    snap = delta < theta
+    snap = _below(delta, theta)
     out_o_ref[...] = jnp.where(snap, x_e, x_o)
-    mask_o_ref[...] = snap.astype(jnp.int8)
+    mask_o_ref[...] = jnp.where(snap, 1, 0).astype(jnp.int8)
 
 
 def reuse_snap_kernel(x_even: jax.Array, x_odd: jax.Array, theta: jax.Array,
                       *, block: int = 256, interpret: bool = False):
-    """x_even/x_odd: (R, P, d) pair-split tokens; theta: (1,) f32.
+    """x_even/x_odd: (R, P, d) pair-split tokens; theta: (1,) f32,
+    already rounded to the tokens' dtype.
 
     Returns (snapped_odd, mask_odd:int8); the even (representative) half
     is unchanged by definition.
@@ -80,7 +86,7 @@ def reuse_snap_kernel(x_even: jax.Array, x_odd: jax.Array, theta: jax.Array,
             jax.ShapeDtypeStruct((R, P, d), x_even.dtype),
             jax.ShapeDtypeStruct((R, P, d), jnp.int8),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -97,9 +103,9 @@ _AXIS_SLOT = {"t": 0, "x": 1, "y": 2}  # θ prefetch layout
 def _gate(delta, theta, granularity: str):
     """Δ < θ at the requested granularity, broadcast back to Δ's shape."""
     if granularity == "channel":
-        return delta < theta
+        return _below(delta, theta)
     # 'token': the mean Δ over channels gates every channel of the token.
-    ok = jnp.mean(delta, axis=-1, keepdims=True) < theta
+    ok = _below(jnp.mean(delta, axis=-1, keepdims=True), theta)
     return jnp.broadcast_to(ok, delta.shape)
 
 
@@ -107,9 +113,18 @@ def _delta2(a0, a1):
     """Window-2 Eq. 3 Δ, with the same op sequence as the host's
     ``reuse.window_delta`` (mean, square, mean, sqrt) — algebraically
     |a1−a0|/2, but kept bitwise-identical so a threshold can never land
-    between the two paths' roundings and flip a mask bit."""
+    between the two paths' roundings and flip a mask bit.  The sqrt
+    runs in f32 and rounds back (the v5e EUP has no bf16 transcendental
+    unit; XLA widens a bf16 sqrt the same way)."""
     m = (a0 + a1) * 0.5
-    return jnp.sqrt((jnp.square(a0 - m) + jnp.square(a1 - m)) * 0.5)
+    var = (jnp.square(a0 - m) + jnp.square(a1 - m)) * 0.5
+    return jnp.sqrt(var.astype(jnp.float32)).astype(var.dtype)
+
+
+def _roll_rows(x, shift: int):
+    """``x`` shifted ``shift`` token rows down (``out[i] = x[i - shift]``)
+    — a sublane rotate, done in f32 (exact for every input dtype)."""
+    return pltpu.roll(x.astype(jnp.float32), shift, 0).astype(x.dtype)
 
 
 def _fused_kernel(theta_ref, x_ref, out_ref, mask_ref,
@@ -120,51 +135,46 @@ def _fused_kernel(theta_ref, x_ref, out_ref, mask_ref,
     (tile rows are the even/odd frames of one t-pair) and TT == 1 for
     single-frame grids.  ``block`` is a multiple of ``2 * width`` so both
     x-neighbours and both y-row partners of every token sit in-tile.
+
+    Every partner is reached by a row rotate of the frame slab (x: one
+    token back, y: one image row back) or by the other frame (t), so
+    the kernel never reshapes or stacks a mask: the per-axis verdicts
+    are plain elementwise values gated by the row parity from an iota.
     """
-    x = x_ref[...]
-    TT, block, d = x.shape
-    masks, reps = {}, {}
+    TT, block, d = x_ref.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (block, d), 0)
+    follows_x = (row % 2) == 1                  # odd token of an x-pair
+    follows_y = (row % (2 * width)) >= width    # odd row of a y-pair
+    x0 = x_ref[0]
+    for f in range(TT):
+        xf = x_ref[f]
+        checks = {}
+        if with_t and f == 1:
+            # t axis: Δ between the two frames; only the odd one snaps.
+            checks["t"] = (_gate(_delta2(x0, xf), theta_ref[_AXIS_SLOT["t"]],
+                                 granularity), x0)
+        rep = _roll_rows(xf, 1)
+        ok = _gate(_delta2(rep, xf), theta_ref[_AXIS_SLOT["x"]], granularity)
+        checks["x"] = (jnp.logical_and(follows_x, ok), rep)
+        rep = _roll_rows(xf, width)
+        ok = _gate(_delta2(rep, xf), theta_ref[_AXIS_SLOT["y"]], granularity)
+        checks["y"] = (jnp.logical_and(follows_y, ok), rep)
 
-    # t axis: Δ between the two frames; only the odd frame ever snaps.
-    if with_t:
-        delta_t = _delta2(x[0], x[1])
-        ok_t = _gate(delta_t, theta_ref[_AXIS_SLOT["t"]], granularity)
-        masks["t"] = jnp.stack([jnp.zeros_like(ok_t), ok_t])
-        reps["t"] = jnp.stack([x[0], x[0]])
-    else:
-        masks["t"] = jnp.zeros(x.shape, jnp.bool_)
-        reps["t"] = x
-
-    # x axis: adjacent even/odd tokens within a row.
-    xp = x.reshape(TT, block // 2, 2, d)
-    delta_x = _delta2(xp[:, :, 0], xp[:, :, 1])
-    ok_x = _gate(delta_x, theta_ref[_AXIS_SLOT["x"]], granularity)
-    masks["x"] = jnp.stack([jnp.zeros_like(ok_x), ok_x],
-                           axis=2).reshape(TT, block, d)
-    reps["x"] = jnp.broadcast_to(xp[:, :, :1], xp.shape) \
-        .reshape(TT, block, d)
-
-    # y axis: adjacent row pairs (rows are ``width`` tokens long).
-    nr = block // width
-    xr = x.reshape(TT, nr // 2, 2, width, d)
-    delta_y = _delta2(xr[:, :, 0], xr[:, :, 1])
-    ok_y = _gate(delta_y, theta_ref[_AXIS_SLOT["y"]], granularity)
-    masks["y"] = jnp.stack([jnp.zeros_like(ok_y), ok_y],
-                           axis=2).reshape(TT, block, d)
-    reps["y"] = jnp.broadcast_to(xr[:, :, :1], xr.shape) \
-        .reshape(TT, block, d)
-
-    # Step ② OR-aggregation, first-wins copy-source priority (the same
-    # semantics as core.reuse.compute_reuse — all masks derive from the
-    # *original* operand, not the progressively snapped one).
-    snapped = x
-    claimed = jnp.zeros(x.shape, jnp.bool_)
-    for a in axes:
-        take = jnp.logical_and(masks[a], jnp.logical_not(claimed))
-        snapped = jnp.where(take, reps[a], snapped)
-        claimed = jnp.logical_or(claimed, masks[a])
-    out_ref[...] = snapped
-    mask_ref[...] = claimed.astype(jnp.int8)
+        # Step ② OR-aggregation, first-wins copy-source priority (the
+        # same semantics as core.reuse.compute_reuse — all masks derive
+        # from the *original* operand, not the progressively snapped
+        # one).
+        snapped = xf
+        claimed = jnp.zeros((block, d), jnp.int32)
+        for a in axes:
+            if a not in checks:
+                continue
+            ok, rep = checks[a]
+            snapped = jnp.where(jnp.logical_and(ok, claimed == 0),
+                                rep, snapped)
+            claimed = jnp.where(ok, 1, claimed)
+        out_ref[f] = snapped
+        mask_ref[f] = claimed.astype(mask_ref.dtype)
 
 
 def fused_reuse_kernel(x: jax.Array, thetas: jax.Array, *,
@@ -201,7 +211,7 @@ def fused_reuse_kernel(x: jax.Array, thetas: jax.Array, *,
             jax.ShapeDtypeStruct((G, TT, S, d), x.dtype),
             jax.ShapeDtypeStruct((G, TT, S, d), jnp.int8),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -20,17 +20,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.reuse_mask.kernel import fused_reuse_kernel, reuse_snap_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
+from repro.utils.platform import on_tpu
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def reuse_snap(x, theta, *, block: int = 256, interpret: bool | None = None):
     """x: (B, H, N, d), theta: scalar -> (snapped x, mask int8)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     B, H, N, d = x.shape
     assert N % 2 == 0
     P = N // 2
@@ -43,7 +40,9 @@ def reuse_snap(x, theta, *, block: int = 256, interpret: bool | None = None):
         padw = ((0, 0), (0, Pp - P), (0, 0))
         x_e = jnp.pad(x_e, padw)
         x_o = jnp.pad(x_o, padw)
-    th = jnp.asarray([theta], jnp.float32).astype(x.dtype)
+    # θ rounded to x's dtype (the host path's compare), carried as f32.
+    th = jnp.asarray([theta], jnp.float32).astype(x.dtype) \
+        .astype(jnp.float32)
     o_o, m_o = reuse_snap_kernel(x_e, x_o, th, block=blk, interpret=interpret)
     o_o, m_o = o_o[:, :P], m_o[:, :P]
 
@@ -84,13 +83,19 @@ def fused_reuse_eligible(grid: Tuple[int, int, int], *, window: int = 2,
     return True
 
 
+# Sublane tiling of the int8 mask output, the coarsest dtype the kernel
+# stores: a block must be a multiple of it (or the whole frame).
+_ROW_TILE = 32
+
+
 def _pick_block(H: int, W: int) -> int:
-    """Largest multiple of 2·W that divides H·W, ≲ the VMEM target."""
-    row_pairs = H // 2
-    m = max(1, min(row_pairs, _TARGET_BLOCK // (2 * W) or 1))
-    while row_pairs % m:
-        m -= 1
-    return m * 2 * W
+    """Largest multiple of 2·W that divides H·W, is a multiple of the
+    (32, 128) int8 tiling and stays ≲ the VMEM target; the whole frame
+    (always a legal block) when no such slab exists."""
+    fits = [m * 2 * W for m in range(1, H // 2 + 1)
+            if (H // 2) % m == 0 and (m * 2 * W) % _ROW_TILE == 0
+            and m * 2 * W <= _TARGET_BLOCK]
+    return max(fits, default=H * W)
 
 
 @functools.partial(
@@ -108,7 +113,7 @@ def fused_reuse_snap(x: jax.Array, thetas: jax.Array, *,
     to its eligible shapes (see :func:`fused_reuse_eligible`).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     T, H, W = grid
     *lead, N, d = x.shape
     assert N == T * H * W, (N, grid)
@@ -118,7 +123,7 @@ def fused_reuse_snap(x: jax.Array, thetas: jax.Array, *,
     S = H * W
     blk = block or _pick_block(H, W)
     x4 = x.reshape(R * (T // TT), TT, S, d)
-    th = thetas.astype(x.dtype)
+    th = thetas.astype(x.dtype).astype(jnp.float32)
     snapped, mask = fused_reuse_kernel(
         x4, th, axes=axes, granularity=granularity, width=W,
         with_t=with_t, block=blk, interpret=interpret)

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 _LANES = 128
 
@@ -182,7 +181,7 @@ def ripple_attention_kernel(
             jax.ShapeDtypeStruct((BH, nq_pairs, dv), q_even.dtype),
             jax.ShapeDtypeStruct((BH, nq_pairs, dv), q_even.dtype),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -20,12 +20,9 @@ import jax.numpy as jnp
 
 from repro.kernels.ripple.kernel import ripple_attention_kernel
 from repro.kernels.ripple.ref import block_flags, split_pairs
+from repro.utils.platform import on_tpu
 
 _PAD_NEG = -1e9
-
-
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
 
 
 @functools.partial(jax.jit,
@@ -38,7 +35,7 @@ def ripple_attention_pallas(q, k, v, *, bias: Optional[jax.Array] = None,
     assert bias is None, "ripple kernel path does not take a bias"
     assert window == 2, "kernel implements the paper's window-2 sweet spot"
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     B, H, N, d = q.shape
     dv = v.shape[-1]
     assert N % 2 == 0, "pair-collapse needs an even token count"

@@ -13,12 +13,18 @@ states:
   logit bias applied in-kernel (−inf entries drop exactly, matching the
   host-side masked softmax).
 
-Two scalar-prefetched fetch-index tables make the skips pay in HBM
-traffic too, not just MXU work: the K/V (and bias) index maps remap a
-skipped tile's block index to the **last non-skipped** one, so
-consecutive grid steps over skipped tiles resolve to the same block and
-the Pallas pipeline elides the copy instead of streaming tiles the
-kernel would never read.
+Two fetch-index tables make the skips pay in HBM traffic too, not just
+MXU work: the K/V (and bias) index maps remap a skipped tile's block
+index to the **last non-skipped** one, so consecutive grid steps over
+skipped tiles resolve to the same block and the Pallas pipeline elides
+the copy instead of streaming tiles the kernel would never read.
+
+The state and both fetch indices travel packed into one int32 per tile
+(:func:`pack_table`) in a flat scalar-prefetched table, and the kernel
+runs one ``pallas_call`` per chunk of (batch·head) rows sized so that
+chunk's table fits SMEM (1 MiB on v5e): at 33k tokens one row's table
+alone is 266 KB, and three unpacked (BH, nq, nk) tables would need
+~10 MB.
 
 The running max is initialized to a large *finite* negative
 (``_M_INIT``) rather than −inf so a partial tile whose entire row is
@@ -37,7 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 _LANES = 128
 # Finite stand-in for -inf in the running max: keeps exp(s - m) defined
@@ -46,11 +51,34 @@ _M_INIT = -1e30
 
 # Block-map states (int32).
 SKIP, FULL, PARTIAL = 0, 1, 2
+# Packed tile entry: state in bits 0-1, the K/V fetch index in bits
+# 2-16, the bias fetch index in bits 17-31.
+_IDX_BITS = 15
+_IDX_MASK = (1 << _IDX_BITS) - 1
+# SMEM bytes one call's packed table may take (SMEM is 1 MiB on v5e).
+_TABLE_BYTES = 512 * 1024
 
 
-def _sparse_kernel(bmap_ref, kfetch_ref, bfetch_ref,
-                   q_ref, k_ref, v_ref, bias_ref,
-                   *refs, scale: float, nk: int, with_state: bool):
+def pack_table(block_map, k_fetch, bias_fetch):
+    """(state, k_fetch, bias_fetch) int32 tables -> one packed table."""
+    return (block_map | (k_fetch << 2)
+            | (bias_fetch << (2 + _IDX_BITS))).astype(jnp.int32)
+
+
+def _state(e):
+    return e & 3
+
+
+def _k_fetch(e):
+    return (e >> 2) & _IDX_MASK
+
+
+def _bias_fetch(e):
+    return (e >> (2 + _IDX_BITS)) & _IDX_MASK
+
+
+def _sparse_kernel(tab_ref, q_ref, k_ref, v_ref, bias_ref,
+                   *refs, scale: float, nq: int, nk: int, with_state: bool):
     if with_state:
         # Cross-hop accumulator convention (DESIGN.md §14): the running
         # (m, l, acc) softmax state enters as three carry inputs and
@@ -64,7 +92,7 @@ def _sparse_kernel(bmap_ref, kfetch_ref, bfetch_ref,
     b = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    state = bmap_ref[b, qi, ki]
+    state = _state(tab_ref[(b * nq + qi) * nk + ki])
 
     @pl.when(ki == 0)
     def _init():
@@ -117,6 +145,87 @@ def _sparse_kernel(bmap_ref, kfetch_ref, bfetch_ref,
             acc_out_ref[...] = acc_ref[...]
 
 
+def _tile(nq: int, nk: int):
+    """Flat index of tile (b, qi, ki) in a chunk's packed table."""
+    return lambda b, qi, ki: (b * nq + qi) * nk + ki
+
+
+def _sparse_call(q, k, v, bias, tab, carry, *, base: int, rows: int,
+                 scale: float, block_q: int, block_k: int, interpret: bool):
+    """One ``pallas_call`` over the ``rows`` (batch·head) rows starting
+    at ``base``: the full operands stay in HBM and the index maps add
+    ``base``, so no operand is sliced or copied per chunk."""
+    BH, Nq, d = q.shape
+    dv = v.shape[2]
+    nq = Nq // block_q
+    nk = k.shape[1] // block_k
+    tile = _tile(nq, nk)
+    dummy_bias = bias.shape[0] == 1 and bias.shape[1:] == (block_q, block_k)
+    with_state = carry is not None
+
+    kernel = functools.partial(_sparse_kernel, scale=scale, nq=nq, nk=nk,
+                               with_state=with_state)
+
+    def qmap(b, qi, ki, tab_ref):
+        return (base + b, qi, 0)
+
+    def omap(b, qi, ki, tab_ref):
+        return (b, qi, 0)
+
+    def kvmap(b, qi, ki, tab_ref):
+        return (base + b, _k_fetch(tab_ref[tile(b, qi, ki)]), 0)
+
+    if dummy_bias:
+        def biasmap(b, qi, ki, tab_ref):
+            return (0, 0, 0)
+    else:
+        def biasmap(b, qi, ki, tab_ref):
+            return (base + b, qi, _bias_fetch(tab_ref[tile(b, qi, ki)]))
+
+    in_specs = [
+        pl.BlockSpec((None, block_q, d), qmap),
+        pl.BlockSpec((None, block_k, d), kvmap),
+        pl.BlockSpec((None, block_k, dv), kvmap),
+        pl.BlockSpec((None, block_q, block_k), biasmap),
+    ]
+    out_specs = pl.BlockSpec((None, block_q, dv), omap)
+    out_shape = jax.ShapeDtypeStruct((rows, Nq, dv), q.dtype)
+    operands = (tab, q, k, v, bias)
+    if with_state:
+        f32 = jnp.float32
+        mspec = pl.BlockSpec((None, block_q, _LANES), qmap)
+        accspec = pl.BlockSpec((None, block_q, dv), qmap)
+        m_out = pl.BlockSpec((None, block_q, _LANES), omap)
+        in_specs = in_specs + [mspec, mspec, accspec]
+        out_specs = [out_specs, m_out, m_out, out_specs]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((rows, Nq, _LANES), f32),
+                     jax.ShapeDtypeStruct((rows, Nq, _LANES), f32),
+                     jax.ShapeDtypeStruct((rows, Nq, dv), f32)]
+        operands = operands + tuple(carry)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(rows, nq, nk),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(*operands)
+
+
 def sparse_attention_kernel(
     q, k, v, bias, block_map, k_fetch, bias_fetch,
     *, scale: float, block_q: int = 128, block_k: int = 128,
@@ -149,73 +258,27 @@ def sparse_attention_kernel(
     nq = Nq // block_q
     nk = Nk // block_k
     assert block_map.shape == (BH, nq, nk), (block_map.shape, BH, nq, nk)
-    dummy_bias = bias.shape[0] == 1 and bias.shape[1:] == (block_q, block_k)
-    with_state = carry is not None
-
-    kernel = functools.partial(_sparse_kernel, scale=scale, nk=nk,
-                               with_state=with_state)
-
-    def qmap(b, qi, ki, *_):
-        return (b, qi, 0)
-
-    def kvmap(b, qi, ki, bmap_ref, kfetch_ref, bfetch_ref):
-        return (b, kfetch_ref[b, qi, ki], 0)
-
-    if dummy_bias:
-        def biasmap(b, qi, ki, *_):
-            return (0, 0, 0)
-    else:
-        def biasmap(b, qi, ki, bmap_ref, kfetch_ref, bfetch_ref):
-            return (b, qi, bfetch_ref[b, qi, ki])
-
-    mspec = pl.BlockSpec((None, block_q, _LANES), qmap)
-    accspec = pl.BlockSpec((None, block_q, dv), qmap)
-    in_specs = [
-        pl.BlockSpec((None, block_q, d), qmap),
-        pl.BlockSpec((None, block_k, d), kvmap),
-        pl.BlockSpec((None, block_k, dv), kvmap),
-        pl.BlockSpec((None, block_q, block_k), biasmap),
-    ]
-    out_specs = accspec
-    out_shape = jax.ShapeDtypeStruct((BH, Nq, dv), q.dtype)
-    operands = (block_map, k_fetch, bias_fetch, q, k, v, bias)
-    if with_state:
+    assert nk <= _IDX_MASK + 1, f"{nk} key blocks overflow the packed table"
+    carry_f = None
+    if carry is not None:
         m_in, l_in, acc_in = carry
         assert m_in.shape == (BH, Nq, _LANES) and \
             l_in.shape == (BH, Nq, _LANES) and \
             acc_in.shape == (BH, Nq, dv), (m_in.shape, l_in.shape,
                                            acc_in.shape)
-        in_specs = in_specs + [mspec, mspec, accspec]
-        out_specs = [out_specs, mspec, mspec, accspec]
-        f32 = jnp.float32
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((BH, Nq, _LANES), f32),
-                     jax.ShapeDtypeStruct((BH, Nq, _LANES), f32),
-                     jax.ShapeDtypeStruct((BH, Nq, dv), f32)]
-        operands = operands + (m_in.astype(f32), l_in.astype(f32),
-                               acc_in.astype(f32))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(BH, nq, nk),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, dv), jnp.float32),
-        ],
-    )
-    res = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(*operands)
-    if with_state:
-        o, m, l, acc = res
-        return o, (m, l, acc)
-    return res
+        carry_f = tuple(c.astype(jnp.float32) for c in carry)
+    tab = pack_table(block_map, k_fetch, bias_fetch)
+    per_row = nq * nk * 4
+    chunk = max(1, min(BH, _TABLE_BYTES // per_row))
+    parts = []
+    for base in range(0, BH, chunk):
+        rows = min(chunk, BH - base)
+        parts.append(_sparse_call(
+            q, k, v, bias, tab[base:base + rows].reshape(-1), carry_f,
+            base=base, rows=rows, scale=scale, block_q=block_q,
+            block_k=block_k, interpret=interpret))
+    if carry is None:
+        return jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+    o, m, l, acc = (jnp.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
+                    for xs in zip(*parts))
+    return o, (m, l, acc)

@@ -26,15 +26,12 @@ import jax.numpy as jnp
 from repro.kernels.sparse.kernel import (_LANES, _M_INIT, FULL, PARTIAL,
                                          SKIP, sparse_attention_kernel)
 from repro.kernels.sparse.ref import sparse_grid
+from repro.utils.platform import on_tpu
 
 __all__ = ["FULL", "PARTIAL", "SKIP", "block_map_from_keep",
            "sparse_attention_pallas", "sparse_block_stats", "sparse_grid"]
 
 _NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
 
 
 def _pad_to(x, target, axis):
@@ -109,7 +106,7 @@ def sparse_attention_pallas(q, k, v, *, bias=None, block_map=None,
     (or an explicit ``acc / l``) is the final answer.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     B, H, Nq, d = q.shape
     Nk = k.shape[2]
     dv = v.shape[3]

@@ -27,11 +27,12 @@ import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs import ALL_ARCHS, get_config, get_smoke_config
 from repro.config.base import apply_overrides
 from repro.core import dispatch as dispatch_lib
-from repro.core.policy import list_policies
+from repro.core.policy import RippleStats, list_policies
 from repro.diffusion.sampler import ddim_sample, euler_flow_sample
 from repro.diffusion.schedule import DDPMSchedule
 from repro.launch.mesh import parse_mesh_spec
@@ -45,12 +46,14 @@ from repro.serving.engine import DiffusionEngine
 from repro.serving.slo import ServiceEstimator, ShedError
 from repro.utils.diskio import atomic_write_text
 from repro.utils.logging import get_logger
+from repro.utils.platform import place_compile_cache
 
 log = get_logger("launch.serve")
 
 
 def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
-                  reuse_every=None, stream_every=None, sentinel=False):
+                  reuse_every=None, stream_every=None, sentinel=False,
+                  reuse_stats=False):
     """Returns sample_fn(noise, txt, rngs) -> latents (or ``(latents,
     aux)`` with decision-cache telemetry) and the latent shape.
     ``rngs`` is the engine's (B, 2) per-request key batch: the initial
@@ -78,7 +81,21 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
     the dispatch layer accumulates per-call attention-output sentinels
     into the decision cache (``aux["sentinel_nonfinite"]`` /
     ``aux["sentinel_drift"]``) — the counters the engine's degradation
-    ladder trips on."""
+    ladder trips on.
+
+    ``reuse_stats=True`` (vdit, monolithic sampler) sums every
+    attention call's :class:`~repro.core.policy.RippleStats` through the
+    scan carry and reports their means over all steps and layers as
+    ``aux["reuse_savings"]``, ``["reuse_structural_savings"]``,
+    ``["reuse_q_snap_frac"]`` and ``["reuse_k_snap_frac"]``: what the
+    reuse path actually did for the batch.  The stats need reductions
+    over all heads, so under a dispatch mesh they keep attention on the
+    replicated path.
+
+    ``params`` enter every jitted program as an argument, never as a
+    closed-over constant: at published widths the weights are gigabytes,
+    and baking them into the program would copy them into the
+    executable."""
     if sentinel:
         arch = dataclasses.replace(
             arch, ripple=dataclasses.replace(arch.ripple, sentinel=True))
@@ -91,6 +108,8 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
                                              reuse_every=int(reuse_every)))
     m = arch.model
     fam = arch.family
+    if reuse_stats and (fam != "vdit" or stream_every):
+        raise ValueError("reuse_stats needs a monolithic vdit sampler")
     steps = shape.steps or 50
     lat_shape = latent_shape_for(arch, shape)
     ddpm = DDPMSchedule()
@@ -130,7 +149,7 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
         K = max(int(stream_every), 1)
 
         @functools.partial(jax.jit, static_argnames=("count",))
-        def chunk_fn(x, txt, rngs, step0, dstate, *, count):
+        def chunk_fn(params, x, txt, rngs, step0, dstate, *, count):
             cond = make_cond(txt, rngs)
             if thread_cache:
                 def denoise(x, t, step, ds):
@@ -187,7 +206,7 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
             nf_total = jnp.zeros((), jnp.int32)
             for s0 in range(start, steps, K):
                 count = min(K, steps - s0)
-                x, dstate, nf = chunk_fn(x, txt, rngs,
+                x, dstate, nf = chunk_fn(params, x, txt, rngs,
                                          jnp.asarray(s0, jnp.int32),
                                          dstate, count=count)
                 aux = {}
@@ -207,22 +226,36 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
         return sample_fn, lat_shape
 
     @jax.jit
-    def sample_fn(noise, txt, rngs):
+    def run(params, noise, txt, rngs):
         cond = make_cond(txt, rngs)
 
-        if thread_cache:
-            def denoise(x, t, step, dstate):
-                out, dstate = _denoise_call(
+        if thread_cache or reuse_stats:
+            # The scan carries (decision cache or None, running sum of
+            # RippleStats or None).
+            def denoise(x, t, step, state):
+                ds, acc = state
+                out, *extra = _denoise_call(
                     arch, params, x, t, cond, step, steps, NULL_CTX,
-                    use_ripple=use_ripple, dstate=dstate)
-                return out.astype(x.dtype), dstate
+                    use_ripple=use_ripple, dstate=ds,
+                    with_stats=reuse_stats)
+                if ds is not None:
+                    ds = extra.pop(0)
+                if reuse_stats:
+                    acc = jax.tree.map(jnp.add, acc, extra.pop(0))
+                return out.astype(x.dtype), (ds, acc)
 
-            dstate = vdit_decision_state(arch, shape.img_res,
+            state = (vdit_decision_state(arch, shape.img_res,
                                          noise.shape[0])
+                     if thread_cache else None,
+                     RippleStats(*[jnp.zeros((), jnp.float32)] * 4)
+                     if reuse_stats else None)
             out = ddim_sample(denoise, noise, ddpm, steps,
-                              decision_state=dstate, sentinel=sentinel)
-            lat, final = out[0], out[1]
-            aux = cache_aux(final, {})
+                              decision_state=state, sentinel=sentinel)
+            lat, (final, acc) = out[0], out[1]
+            aux = cache_aux(final, {}) if thread_cache else {}
+            if reuse_stats:
+                for f in dataclasses.fields(acc):
+                    aux[f"reuse_{f.name}"] = getattr(acc, f.name) / steps
             if sentinel:
                 aux["latent_nonfinite"] = out[2]
             return lat, aux
@@ -242,11 +275,11 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
             return out[0], {"latent_nonfinite": out[1]}
         return out
 
-    return sample_fn, lat_shape
+    return functools.partial(run, params), lat_shape
 
 
 def make_sampler_factory(arch, shapes, params, *, use_ripple=True,
-                         mesh=None, sentinel=False):
+                         mesh=None, sentinel=False, reuse_stats=False):
     """(engine sampler_factory, plan_fn) over a set of generate cells,
     keyed by the engine's (latent_shape, steps, policy, reuse_every,
     stream_every) bucket identity.  The engine hands both callables the
@@ -264,7 +297,7 @@ def make_sampler_factory(arch, shapes, params, *, use_ripple=True,
         fn, _ = build_sampler(arch, sp, params, use_ripple=use_ripple,
                               policy=policy, reuse_every=reuse_every,
                               stream_every=stream_every,
-                              sentinel=sentinel)
+                              sentinel=sentinel, reuse_stats=reuse_stats)
         return fn
 
     def plan_fn(latent_shape, steps, policy=None):
@@ -330,6 +363,9 @@ def main(argv=None):
                     help="single-shape traffic from this named shape; "
                          "default: a mixed-shape request stream")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--steps", type=int, default=None,
+                    help="denoising steps for every traffic bucket "
+                         "(default: each shape's own step count)")
     ap.add_argument("--mesh", default=None, metavar="DxMxS",
                     help="(data, model[, seq]) mesh, e.g. 8, 4x2 or "
                          "1x1x2; shards the attention dispatch under "
@@ -386,6 +422,11 @@ def main(argv=None):
                          "moves more than TOL (relative) from the cached "
                          "decision's reference.  0 disables (default: "
                          "the arch config's ripple.drift_tol)")
+    ap.add_argument("--reuse-stats", action="store_true",
+                    help="report what the reuse path did per batch: mean "
+                         "savings and q/k snap fractions over every "
+                         "attention call (vdit, monolithic buckets, no "
+                         "--mesh: the stats reduce over all heads)")
     ap.add_argument("--attn-backend", default=None,
                     choices=("auto", "dense", "reference", "collapse",
                              "pallas", "sparse"),
@@ -448,6 +489,8 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("overrides", nargs="*")
     args = ap.parse_args(argv)
+    cache_dir = place_compile_cache()
+    log.info("compile cache: %s", cache_dir)
 
     if args.distributed:
         from repro.launch.mesh import init_distributed
@@ -470,6 +513,8 @@ def main(argv=None):
                  f"{list_policies()} (use --policy-module to register "
                  f"an out-of-tree policy first)")
 
+    if args.reuse_stats and (args.mesh or args.stream_every):
+        ap.error("--reuse-stats needs monolithic buckets and no --mesh")
     mesh = parse_mesh_spec(args.mesh) if args.mesh else None
     if mesh is not None:
         dispatch_lib.set_dispatch_mesh(mesh)
@@ -490,6 +535,9 @@ def main(argv=None):
         shapes = (arch.shape(args.shape),)
     else:
         shapes = mixed_gen_shapes(arch, smoke=args.smoke)
+    if args.steps is not None:
+        shapes = tuple(dataclasses.replace(s, steps=args.steps)
+                       for s in shapes)
     log.info("traffic buckets: %s",
              [(s.name, s.img_res, s.steps) for s in shapes])
 
@@ -579,7 +627,7 @@ def main(argv=None):
     params = init_params(defs, jax.random.PRNGKey(args.seed))
     factory, plan_fn = make_sampler_factory(
         arch, shapes, params, use_ripple=not args.no_ripple, mesh=mesh,
-        sentinel=guardrail)
+        sentinel=guardrail, reuse_stats=args.reuse_stats)
 
     def make_engine():
         return DiffusionEngine(sampler_factory=factory,
@@ -626,6 +674,10 @@ def main(argv=None):
     submitted = []
     completed = []
     errors = {}
+    degraded = []
+    walltimes = {}
+    reuse = {}
+    latents = {}
     try:
         for req in recovered:
             sp = types.SimpleNamespace(name="recovered", steps=req.steps)
@@ -656,7 +708,18 @@ def main(argv=None):
                 errors[req.request_id] = str(e)
                 log.error("request %d failed: %s", req.request_id, e)
                 continue
+            if not np.isfinite(np.asarray(r.latents)).all():
+                errors[req.request_id] = "non-finite latents"
+                log.error("request %d returned non-finite latents",
+                          req.request_id)
+                continue
             completed.append(req.request_id)
+            walltimes[req.request_id] = r.walltime_s
+            if r.reuse is not None:
+                reuse[req.request_id] = r.reuse
+            latents[req.request_id] = r.latents
+            if r.degraded:
+                degraded.append(req.request_id)
             log.info("request %d (%s, %d steps) done in %.2fs "
                      "(ttff %.3fs%s%s%s); latents %s",
                      req.request_id, sp.name, sp.steps, r.walltime_s,
@@ -691,23 +754,35 @@ def main(argv=None):
              len(completed), args.requests + len(recovered), shed,
              len(recovered), resumed_from, len(shapes),
              time.time() - t0)
+    summary = {
+        "submitted": [req.request_id for _, req in submitted],
+        "completed": completed,
+        "errors": {str(k): v for k, v in errors.items()},
+        "degraded": degraded,
+        "walltime_s": {str(k): v for k, v in walltimes.items()},
+        "reuse": {str(k): v for k, v in reuse.items()},
+        "shed": shed,
+        "recovered": len(recovered),
+        "resumed_from_step": resumed_from,
+        "sigterm": terminating["sigterm"],
+        "counters": {k: (float(v) if isinstance(v, float) else int(v))
+                     for k, v in counters.items()},
+    }
     if args.summary_json:
-        summary = {
-            "submitted": [req.request_id for _, req in submitted],
-            "completed": completed,
-            "errors": {str(k): v for k, v in errors.items()},
-            "shed": shed,
-            "recovered": len(recovered),
-            "resumed_from_step": resumed_from,
-            "sigterm": terminating["sigterm"],
-            "counters": {k: (float(v) if isinstance(v, float) else int(v))
-                         for k, v in counters.items()},
-        }
         with open(args.summary_json, "w", encoding="utf-8") as f:
             json.dump(summary, f, indent=1, sort_keys=True)
         log.info("wrote summary to %s", args.summary_json)
     if terminating["sigterm"]:
         sys.exit(143)
+    # A failed request fails the run; so does a guardrail degradation
+    # unless faults were injected on purpose (the chaos drill expects
+    # the ladder to step down).
+    if errors or (degraded and fault is None):
+        log.error("%d request(s) failed, %d degraded", len(errors),
+                  len(degraded))
+        sys.exit(1)
+    summary["latents"] = latents
+    return summary
 
 
 if __name__ == "__main__":

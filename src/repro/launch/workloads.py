@@ -283,18 +283,19 @@ def _diffusion_batch_specs(arch: ArchConfig, shape: ShapeSpec, mesh,
 
 
 def _denoise_call(arch: ArchConfig, params, x, t, cond, step, total, ctx,
-                  use_ripple: bool, dstate=None):
+                  use_ripple: bool, dstate=None, with_stats=False):
     """One denoiser forward.  ``dstate`` threads the per-layer decision
     cache (DESIGN.md §13) — vdit only; the call then returns
-    ``(out, new_dstate)``."""
+    ``(out, new_dstate)``.  ``with_stats`` (vdit only) appends the
+    layer-mean :class:`~repro.core.policy.RippleStats`."""
     fam = arch.family
     m = arch.model
     rip = arch.ripple if use_ripple else dataclasses.replace(
         arch.ripple, enabled=False)
     kw = dict(ripple=rip, step=step, total_steps=total, ctx=ctx)
-    if dstate is not None and fam != "vdit":
-        raise ValueError(f"decision-cache state is only threaded through "
-                         f"the vdit family, not {fam!r}")
+    if (dstate is not None or with_stats) and fam != "vdit":
+        raise ValueError(f"decision-cache state and reuse stats are only "
+                         f"threaded through the vdit family, not {fam!r}")
     if fam == "dit":
         out = dit_lib.dit_apply(params, x, t, cond["labels"], m, **kw)
         return out[..., : m.in_channels]  # drop sigma for the ODE path
@@ -305,7 +306,8 @@ def _denoise_call(arch: ArchConfig, params, x, t, cond, step, total, ctx,
         return unet_lib.unet_apply(params, x, t, cond["ctx"], m, **kw)
     if fam == "vdit":
         return vdit_lib.vdit_apply(params, x, t, cond["txt"], m,
-                                   decision_state=dstate, **kw)
+                                   decision_state=dstate,
+                                   with_stats=with_stats, **kw)
     raise ValueError(fam)
 
 

@@ -215,6 +215,7 @@ def mha_attention(
     backend: Optional[str] = None,
     cached_decision=None,
     return_decision: bool = False,
+    with_stats: bool = False,
     ctx: ShardCtx = NULL_CTX,
 ):
     """Bidirectional MHA through the dispatch layer. x: (B, N, d).
@@ -230,7 +231,9 @@ def mha_attention(
     decision cache (DESIGN.md §13) through to ``attention_dispatch``;
     when either is set the layer returns ``(out, CachedDecision)`` so
     the model can carry per-layer decision state across denoising
-    steps.  Self-attention only (cross-attention has no grid)."""
+    steps.  Self-attention only (cross-attention has no grid).
+    ``with_stats`` appends the call's
+    :class:`~repro.core.policy.RippleStats` to the return."""
     from repro.models.common import apply_rope_precomputed
 
     dt = x.dtype
@@ -261,22 +264,14 @@ def mha_attention(
     if want_cache and encoder_out is not None:
         raise ValueError("decision caching applies to grid self-attention "
                          "only, not cross-attention")
-    new_cache = None
-    if want_cache:
-        out, new_cache = attention_dispatch(
-            q, k, v, grid=grid, cfg=ripple, step=step,
-            total_steps=total_steps, grid_slice=grid_slice,
-            backend=eff_backend, cached_decision=cached_decision,
-            return_decision=True)
-    else:
-        out = attention_dispatch(
-            q, k, v, grid=grid, cfg=ripple, step=step,
-            total_steps=total_steps, grid_slice=grid_slice,
-            backend=eff_backend)
+    res = attention_dispatch(
+        q, k, v, grid=grid, cfg=ripple, step=step,
+        total_steps=total_steps, grid_slice=grid_slice,
+        backend=eff_backend, cached_decision=cached_decision,
+        return_decision=want_cache, with_stats=with_stats)
+    out, *extra = res if (want_cache or with_stats) else (res,)
 
     out = out.transpose(0, 2, 1, 3).reshape(B, N, n_heads * head_dim)
     out = jnp.einsum("bnh,hd->bnd", out, params["wo"].astype(dt))
     out = ctx.c(out, ("batch", "seq", "embed"))
-    if want_cache:
-        return out, new_cache
-    return out
+    return (out, *extra) if extra else out

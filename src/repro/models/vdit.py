@@ -90,6 +90,7 @@ def vdit_apply(
     compute_dtype=jnp.bfloat16,
     remat: bool = False,
     decision_state=None,
+    with_stats: bool = False,
 ) -> jax.Array:
     """Apply the vDiT.  ``decision_state`` (optional) is the per-layer
     cross-step decision-cache state (DESIGN.md §13): a stacked
@@ -98,7 +99,9 @@ def vdit_apply(
     it).  Each layer's slice rides the scan-over-layers as a per-layer
     input and the updated slices are restacked, so the sampler can carry
     the whole thing through its denoising scan; the function then
-    returns ``(out, new_decision_state)``."""
+    returns ``(out, new_decision_state)``.  ``with_stats`` appends a
+    :class:`~repro.core.policy.RippleStats` whose fields are the means
+    over the layers' attention calls."""
     dt = compute_dtype
     B, T, H, W, C = latents.shape
     tg, hg, wg = T // cfg.t_patch, H // cfg.patch, W // cfg.patch
@@ -135,18 +138,21 @@ def vdit_apply(
         ada = linear(bp["ada"], c)
         sh1, sc1, g1, sh2, sc2, g2 = jnp.split(ada, 6, axis=-1)
         h_ = layernorm({}, x) * (1 + sc1[:, None]) + sh1[:, None]
-        attn = mha_attention(
+        res = mha_attention(
             bp["attn"], h_, n_heads=cfg.num_heads, head_dim=hd, grid=grid,
             ripple=ripple, step=step, total_steps=total_steps,
             rope_cos=rope_cos, rope_sin=rope_sin,
             grid_slice=(L_txt, n_img), cached_decision=dcache,
-            return_decision=dcache is not None, ctx=ctx)
+            return_decision=dcache is not None, with_stats=with_stats,
+            ctx=ctx)
+        attn, *extra = res if (dcache is not None or with_stats) else (res,)
         if dcache is not None:
-            attn, dcache = attn
+            dcache = extra.pop(0)
+        stats = extra.pop(0) if with_stats else None
         x = x + g1[:, None] * attn
         h_ = layernorm({}, x) * (1 + sc2[:, None]) + sh2[:, None]
         x = x + g2[:, None] * mlp(bp["mlp"], h_)
-        return ctx.c(x, ("batch", "seq", "embed")), dcache
+        return ctx.c(x, ("batch", "seq", "embed")), (dcache, stats)
 
     if decision_state is None:
         def body(x, bp):
@@ -159,12 +165,15 @@ def vdit_apply(
 
     if remat:
         body = jax.checkpoint(body)
-    x, new_state = scan_layers(body, x, xs)
+    x, (new_state, stats) = scan_layers(body, x, xs)
 
     sh, sc = jnp.split(linear(params["final_ada"], c), 2, axis=-1)
     x = layernorm({}, x[:, L_txt:]) * (1 + sc[:, None]) + sh[:, None]
     x = linear(params["final"], x)
     out = unpatchify_3d(x, cfg.t_patch, cfg.patch, tg, hg, wg, C)
+    res = (out,)
     if decision_state is not None:
-        return out, new_state
-    return out
+        res += (new_state,)
+    if with_stats:
+        res += (jax.tree.map(jnp.mean, stats),)
+    return res if len(res) > 1 else out

@@ -218,6 +218,11 @@ class GenResult:
     # Was the serving bucket degraded below its requested reuse policy
     # by the guardrail ladder when this result was produced (§17.2)?
     degraded: bool = False
+    # Reuse telemetry of the batch that served this request, read from
+    # the sampler's aux: decision-cache hits/refreshes (§13) and, from
+    # samplers built with ``reuse_stats``, the mean savings and q/k
+    # snap fractions over every attention call.
+    reuse: Optional[Dict[str, float]] = None
 
 
 class DiffusionEngine:
@@ -728,24 +733,35 @@ class DiffusionEngine:
             return out[0], out[1]
         return out, None
 
-    def _log_aux(self, key: BucketKey, aux: Optional[dict]):
-        """Cache-aware samplers return decision-cache telemetry
-        (DESIGN.md §13) — log the hit rate so the amortization is
-        observable in serving, not just benches."""
+    def _read_aux(self, key: BucketKey,
+                  aux: Optional[dict]) -> Optional[Dict[str, float]]:
+        """Log and return the batch's reuse telemetry: decision-cache
+        hits/refreshes (DESIGN.md §13), so the amortization is
+        observable in serving, not just benches, and the
+        ``reuse_*`` stats of samplers built with ``reuse_stats``."""
         if not aux:
-            return
+            return None
         hits = int(jax.device_get(aux.get("cache_hits", 0)))
         refr = int(jax.device_get(aux.get("cache_refreshes", 0)))
+        reuse = {"cache_hits": hits, "cache_refreshes": refr}
         if hits + refr:
             log.info(
                 "bucket %s decision cache: %d hits / %d refreshes "
                 "(hit rate %.2f)", key, hits, refr,
                 hits / max(hits + refr, 1))
+        stats = {k: float(jax.device_get(v)) for k, v in aux.items()
+                 if k.startswith("reuse_")}
+        if stats:
+            reuse.update(stats)
+            log.info("bucket %s reuse: %s", key,
+                     ", ".join(f"{k[6:]} {v:.4f}"
+                               for k, v in stats.items()))
         if "ring_elided_hops" in aux:
             # Context-parallel telemetry (DESIGN.md §14): ring hops the
             # block map let the seq shards skip.
             log.info("bucket %s ring: %d elided hop(s)", key,
                      int(jax.device_get(aux["ring_elided_hops"])))
+        return reuse
 
     # -- guardrail / watchdog serve path (DESIGN.md §17) ----------------------
 
@@ -1015,7 +1031,8 @@ class DiffusionEngine:
     def _publish_batch(self, key: BucketKey,
                        batch: List[Tuple[float, GenRequest]],
                        lat: np.ndarray, dt: float, pub: Dict,
-                       err: Optional[str], degraded: bool):
+                       err: Optional[str], degraded: bool,
+                       reuse: Optional[Dict[str, float]] = None):
         now = time.time()
         with self._lock:
             bi = self._batches_served
@@ -1033,7 +1050,8 @@ class DiffusionEngine:
                     batch_index=bi,
                     ttff_s=pub["ttff"].get(r.request_id,
                                            -1.0 if err else now - t_enq),
-                    deadline_met=met, degraded=degraded)
+                    deadline_met=met, degraded=degraded,
+                    reuse=None if err else reuse)
                 if err is not None:
                     self._error_expiry[r.request_id] = (
                         time.time() + self.error_ttl_s)
@@ -1109,14 +1127,15 @@ class DiffusionEngine:
                 self._ladder.record_clean(fam)
             if exc is None:
                 dt = time.time() - t0
-                self._log_aux(eff_key, res.get("aux"))
+                reuse = self._read_aux(eff_key, res.get("aux"))
                 self.estimator.observe(key, dt)
                 if eff_key != key:
                     self.estimator.observe(eff_key, dt)
                 self._publish_batch(
                     key, batch, res["lat"], dt, pub, None,
                     degraded=(self._ladder is not None
-                              and self._ladder.degraded(fam)))
+                              and self._ladder.degraded(fam)),
+                    reuse=reuse)
                 log.info("served bucket %s batch of %d in %.2fs%s", key,
                          len(batch), dt,
                          " (degraded)" if eff_key != key else "")

@@ -4,11 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # degrade property tests to fixed-example tests
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.config.base import RippleConfig
 from repro.core import reuse, savings

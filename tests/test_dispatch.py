@@ -9,11 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # degrade to fixed-example property checks
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.config.base import RippleConfig
 from repro.core import dispatch
@@ -137,9 +133,10 @@ class TestFusedMask:
         ((2, 2, 2), ()),
     ])
     @pytest.mark.parametrize("granularity", ["channel", "token"])
-    def test_matches_host_pipeline(self, grid, lead, granularity):
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_matches_host_pipeline(self, grid, lead, granularity, dtype):
         n = grid[0] * grid[1] * grid[2]
-        x = jax.random.normal(jax.random.PRNGKey(0), (*lead, n, D))
+        x = jax.random.normal(jax.random.PRNGKey(0), (*lead, n, D), dtype)
         th = {a: jnp.asarray(0.6, jnp.float32) for a in ("t", "x", "y")}
         assert fused_reuse_eligible(grid, granularity=granularity)
         r = compute_reuse(x, grid, th, granularity=granularity)

@@ -7,6 +7,7 @@ checkpointed-failover snapshot."""
 
 import json
 import os
+import tempfile
 import threading
 import time
 
@@ -14,11 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # degrade property tests to fixed-example tests
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import decision_cache
 from repro.serving import journal as journal_lib
@@ -47,21 +44,24 @@ def _req(rid, **kw):
 class TestFraming:
     @settings(max_examples=20, deadline=None)
     @given(n=st.integers(1, 12), rid0=st.integers(0, 999))
-    def test_append_scan_round_trip(self, tmp_path, n, rid0):
+    def test_append_scan_round_trip(self, n, rid0):
         """Property: any sequence of lifecycle appends scans back
-        intact, in order, with contiguous sequence numbers."""
-        d = str(tmp_path / f"j{n}_{rid0}")
-        j = Journal(d, fsync="never")
-        events = []
-        for i in range(n):
-            ev = ("submitted", "chunk", "finished", "shed")[i % 4]
-            j.append(ev, rid0 + i, i=i)
-            events.append((ev, rid0 + i))
-        j.close(clean=False)
-        records, torn = scan_records(os.path.join(d, "journal.log"))
-        assert not torn
-        assert [(r["ev"], r["rid"]) for r in records] == events
-        assert [r["seq"] for r in records] == list(range(1, n + 1))
+        intact, in order, with contiguous sequence numbers.  Each
+        example gets its own directory (a function-scoped ``tmp_path``
+        would be shared by every example)."""
+        with tempfile.TemporaryDirectory() as tmp:
+            d = os.path.join(tmp, "journal")
+            j = Journal(d, fsync="never")
+            events = []
+            for i in range(n):
+                ev = ("submitted", "chunk", "finished", "shed")[i % 4]
+                j.append(ev, rid0 + i, i=i)
+                events.append((ev, rid0 + i))
+            j.close(clean=False)
+            records, torn = scan_records(os.path.join(d, "journal.log"))
+            assert not torn
+            assert [(r["ev"], r["rid"]) for r in records] == events
+            assert [r["seq"] for r in records] == list(range(1, n + 1))
 
     def test_fsync_policies(self, tmp_path):
         for policy in ("always", "interval", "never"):

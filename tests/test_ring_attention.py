@@ -5,9 +5,7 @@ Property-based parity: the ring path (token axis sharded over the
 grids, windows, and policies at 2/4/8-way seq shards — bitwise for the
 snap policies (ripple, equal_mse) and for dense's fallback, and to the
 documented ~1e-5 relative tolerance for svg (hop order rotates the
-online-softmax reduction per shard).  The fixed-example fallback in
-``_hypothesis_compat`` keeps the properties spot-checked when
-``hypothesis`` is absent.
+online-softmax reduction per shard).
 
 Also here, always-on (single-device): the sparse kernel's ring-hop
 carry convention — chaining calls over K column slices equals one
@@ -22,11 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # degrade to fixed-example property checks
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from conftest import require_devices
 from repro.config.base import RippleConfig

@@ -580,3 +580,105 @@ class TestStreamingDelivery:
         assert all(np.all(np.isfinite(c)) for c in chunks)
         np.testing.assert_allclose(chunks[-1], r.latents)
         assert eng.metrics()["dense_fallbacks"] == 1
+
+
+class TestLauncherExit:
+    """``python -m repro.launch.serve`` fails loudly: a request that
+    errors makes the process exit non-zero instead of logging and
+    exiting 0, and a clean run returns its summary."""
+
+    ARGV = ["--smoke", "--shape", "gen_512", "--steps", "2",
+            "--requests", "1", "--max-batch", "1", "model.num_layers=1"]
+
+    @pytest.fixture(autouse=True)
+    def _isolated(self, tmp_path, monkeypatch):
+        from repro.serving import faults
+
+        # An explicit cache directory: the launcher then leaves JAX's
+        # compile-cache config of this test process alone.
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        yield
+        faults.clear_faults()
+
+    def test_clean_run_returns_summary(self):
+        from repro.launch import serve
+
+        summary = serve.main(self.ARGV)
+        assert summary["completed"] == [0] and not summary["errors"]
+        assert not summary["degraded"]
+        assert np.isfinite(summary["latents"][0]).all()
+
+    def test_failed_request_exits_nonzero(self):
+        from repro.launch import serve
+
+        with pytest.raises(SystemExit) as e:
+            serve.main(self.ARGV + ["--inject-faults", "poison:rid=0"])
+        assert e.value.code == 1
+
+
+class TestReuseStats:
+    """``--reuse-stats``: each served request reports what the reuse
+    path did (mean savings and q/k snap fractions over its attention
+    calls) without changing its latents."""
+
+    # Four steps: with a cadence of 2 the decision made at step 2 (past
+    # the dense step 0) is re-applied at step 3.
+    ARGV = ["--smoke", "--shape", "gen_512", "--steps", "4",
+            "--requests", "1", "--max-batch", "1", "model.num_layers=1",
+            "ripple.i_min=1", "ripple.i_max=2"]
+
+    @pytest.fixture(autouse=True)
+    def _isolated(self, tmp_path, monkeypatch):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
+    @pytest.mark.parametrize("reuse_every", [1, 2])
+    def test_request_reports_snaps_and_keeps_latents(self, reuse_every):
+        from repro.launch import serve
+
+        argv = self.ARGV + ["--reuse-every", str(reuse_every)]
+        plain = serve.main(argv)
+        stats = serve.main(argv + ["--reuse-stats"])
+        r = stats["reuse"]["0"]
+        assert 0 < r["reuse_q_snap_frac"] <= 1
+        assert 0 < r["reuse_k_snap_frac"] <= 1
+        assert 0 < r["reuse_savings"] <= 1
+        assert 0 <= r["reuse_structural_savings"] <= 1
+        assert (r["cache_hits"] > 0) == (reuse_every > 1)
+        assert not any(k.startswith("reuse_")
+                       for k in plain["reuse"].get("0", {}))
+        np.testing.assert_array_equal(plain["latents"][0],
+                                      stats["latents"][0])
+
+    def test_refused_under_a_mesh(self):
+        from repro.launch import serve
+
+        with pytest.raises(SystemExit) as e:
+            serve.main(self.ARGV + ["--reuse-stats", "--mesh", "1x1"])
+        assert e.value.code == 2
+
+
+class TestCompileCachePlacement:
+    def test_env_dir_wins_and_config_is_untouched(self, monkeypatch,
+                                                  tmp_path):
+        from repro.utils import platform
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert platform.place_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_dir_in_checkout(self, monkeypatch):
+        import os
+
+        from repro.utils import platform
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            got = platform.place_compile_cache()
+            assert got == platform.DEFAULT_COMPILE_CACHE
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(root, ".jax_compilation_cache")

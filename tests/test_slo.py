@@ -11,11 +11,7 @@ import time
 
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # degrade property tests to fixed-example tests
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.engine import DiffusionEngine, GenRequest
 from repro.serving.router import Router
