@@ -57,7 +57,7 @@ from repro.core import reuse, savings as savings_lib
 from repro.core.collapse import pair_major_order
 from repro.data.synthetic import correlated_video_latents
 from repro.kernels.reuse_mask.ops import fused_compute_reuse
-from repro.kernels.ripple.ops import ripple_block_stats
+from repro.kernels.ripple.ops import ripple_block_flags, ripple_block_stats
 
 GRID = (8, 16, 16)
 N = GRID[0] * GRID[1] * GRID[2]
@@ -85,14 +85,14 @@ def run():
         rk = reuse.compute_reuse(k, GRID, th, granularity="token")
         paper = float(savings_lib.partial_score_savings(rq.mask, rk.mask))
         pq, pk = savings_lib.pair_collapse_fractions(rq.mask, rk.mask)
-        skip_raw = float(ripple_block_stats(rq.snapped, rk.snapped,
-                                            block_q=128, block_k=128))
+        skip_raw = float(ripple_block_stats(*ripple_block_flags(
+            rq.snapped, rk.snapped, block_q=128, block_k=128)))
         # pair-major reorder along x (already adjacent) vs t
         perm = jnp.asarray(pair_major_order(GRID, "t"))
         q_t = rq.snapped[..., perm, :]
         k_t = rk.snapped[..., perm, :]
-        skip_tmajor = float(ripple_block_stats(q_t, k_t, block_q=128,
-                                               block_k=128))
+        skip_tmajor = float(ripple_block_stats(*ripple_block_flags(
+            q_t, k_t, block_q=128, block_k=128)))
         # collapse-aware scheduling: protect t-representatives from x/y
         # snaps so the pair structure survives high thresholds
         rq_p = reuse.compute_reuse(q, GRID, th, granularity="token",
@@ -101,9 +101,9 @@ def run():
                                    protect_axis="t")
         paper_p = float(savings_lib.partial_score_savings(rq_p.mask,
                                                           rk_p.mask))
-        skip_prot = float(ripple_block_stats(
+        skip_prot = float(ripple_block_stats(*ripple_block_flags(
             rq_p.snapped[..., perm, :], rk_p.snapped[..., perm, :],
-            block_q=128, block_k=128))
+            block_q=128, block_k=128)))
         rows.append({
             "target": target, "theta": round(theta, 4),
             "paper_savings": round(paper, 3),

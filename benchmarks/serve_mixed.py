@@ -5,9 +5,8 @@ Three sections, all on the miniature vDiT over a deterministic
 round-robin stream across three (resolution, steps) buckets:
 
 1. **bucketed vs single** — the bucketed continuous-batching engine vs
-   the seed-style one-engine-per-shape baseline.  The structural
-   headline is the device-utilization proxy (Σ batch compute walltime /
-   stream walltime) and that mixed traffic needs no per-shape engines.
+   the seed-style one-engine-per-shape baseline: mixed traffic needs no
+   per-shape engines.
 2. **scheduler policies** — the same deadline-stamped overload trace
    served under ``hottest`` (pre-SLO drain order) and ``edf``
    (deadline-aware, DESIGN.md §15.1).  Requests stream chunked latents
@@ -24,8 +23,7 @@ which also seeds the admission estimator.  CPU wall time is relative
 only (one serial device serves every bucket).
 
 Reported rows (CSV: name,us_per_call,derived):
-  serve_mixed[bucketed_p50/p95]  — per-request latency percentiles (us);
-                                   derived = utilization proxy
+  serve_mixed[bucketed_p50/p95]  — per-request latency percentiles (us)
   serve_mixed[single_p50/p95]    — same for the sequential baseline
   serve_mixed[speedup]           — stream walltime ratio (baseline /
                                    bucketed); derived = bucketed stream
@@ -84,7 +82,7 @@ REQUESTS = 9
 
 def _drive(engine, traffic):
     """Submit the whole stream to a *started* engine, wait for every
-    result; returns (per-request latencies, stream walltime, busy time).
+    result; returns (per-request latencies, stream walltime).
     Run once to warm (compiles) and once to measure steady state — the
     deterministic stream reproduces the same batch shapes, so the timed
     pass never compiles."""
@@ -93,13 +91,12 @@ def _drive(engine, traffic):
     for _, req in traffic:
         submit_t[req.request_id] = time.time()
         engine.submit(req)
-    lat, busy = [], {}
+    lat = []
     for _, req in traffic:
-        r = engine.result(req.request_id, timeout=600)
+        engine.result(req.request_id, timeout=600)
         lat.append(time.time() - submit_t[req.request_id])
-        busy[r.batch_index] = r.walltime_s  # one entry per served batch
     wall = time.time() - t0
-    return np.asarray(lat), wall, sum(busy.values())
+    return np.asarray(lat), wall
 
 
 def _drive_slo(front, traffic, deadline_ms, *, shed_probe=None):
@@ -151,36 +148,29 @@ def _bucketed_vs_single(arch, shapes, params, traffic, rows):
                           max_batch=4, max_wait_s=0.02)
     eng.start()
     _drive(eng, traffic)  # warm: compiles every bucket's sampler
-    b_lat, b_wall, b_busy = _drive(eng, traffic)
+    b_lat, b_wall = _drive(eng, traffic)
     eng.stop()
 
     # Seed-style baseline: one single-shape engine per bucket, shapes
     # served sequentially (requests still batch within their own shape).
-    s_lat_all, s_wall, s_busy = [], 0.0, 0.0
+    s_lat_all, s_wall = [], 0.0
     for sp in shapes:
         fn, lat_shape = build_sampler(arch, sp, params)
         sub = [(s, r) for s, r in traffic if s.name == sp.name]
         single = DiffusionEngine(fn, lat_shape, max_batch=4, max_wait_s=0.02)
         single.start()
         _drive(single, sub)  # warm
-        lat, wall, busy = _drive(single, sub)
+        lat, wall = _drive(single, sub)
         single.stop()
         s_lat_all.append(lat)
         s_wall += wall
-        s_busy += busy
     s_lat = np.concatenate(s_lat_all)
 
-    b_util = b_busy / max(b_wall, 1e-9)
-    s_util = s_busy / max(s_wall, 1e-9)
     rows += [
-        f"serve_mixed[bucketed_p50],{np.percentile(b_lat, 50) * 1e6:.0f},"
-        f"{b_util:.3f}",
-        f"serve_mixed[bucketed_p95],{np.percentile(b_lat, 95) * 1e6:.0f},"
-        f"{b_util:.3f}",
-        f"serve_mixed[single_p50],{np.percentile(s_lat, 50) * 1e6:.0f},"
-        f"{s_util:.3f}",
-        f"serve_mixed[single_p95],{np.percentile(s_lat, 95) * 1e6:.0f},"
-        f"{s_util:.3f}",
+        f"serve_mixed[bucketed_p50],{np.percentile(b_lat, 50) * 1e6:.0f},",
+        f"serve_mixed[bucketed_p95],{np.percentile(b_lat, 95) * 1e6:.0f},",
+        f"serve_mixed[single_p50],{np.percentile(s_lat, 50) * 1e6:.0f},",
+        f"serve_mixed[single_p95],{np.percentile(s_lat, 95) * 1e6:.0f},",
         f"serve_mixed[speedup],{s_wall / max(b_wall, 1e-9):.2f},"
         f"{b_wall:.2f}",
     ]

@@ -24,8 +24,8 @@ Phases (one process; each served phase calls ``repro.launch.serve.main``):
   reference.
 * ``ripple`` — two requests of the default ``ripple`` policy, ``auto``
   backend: every plan must resolve to ``pallas`` with the fused Δ-check,
-  and each request must report snapped q and k entries
-  (``--reuse-stats``).
+  and each request must report snapped q and k entries (the reuse
+  counters every vdit request reports).
 * ``svg`` — one ``--policy svg`` request: every plan must be ``sparse``.
 
 Any failed, shed, degraded or non-finite request, a wrong plan, a
@@ -91,10 +91,10 @@ def peak_memory() -> str:
 
 
 def serve_argv(*, policy=None, layers=LAYERS, steps=STEPS, requests=2,
-               extra=(), overrides=()):
+               overrides=()):
     argv = ["--arch", "vdit-paper", "--shape", "gen_512",
             "--steps", str(steps), "--requests", str(requests),
-            "--max-batch", "1", "--seed", "0", *extra]
+            "--max-batch", "1", "--seed", "0"]
     if policy:
         argv += ["--policy", policy]
     return argv + [f"model.num_layers={layers}", *RAMP, *overrides]
@@ -225,7 +225,7 @@ def attention_check(arch, img_res: int):
         host = compute_reuse(x[:, :, seg], grid, th, axes=cfg.axes,
                              window=cfg.window, granularity=cfg.granularity,
                              channel_groups=cfg.channel_groups)
-        f_snap, f_mask = fused_compute_reuse(x[:, :, seg], grid, th,
+        f_snap, f_mask, _ = fused_compute_reuse(x[:, :, seg], grid, th,
                                              axes=cfg.axes,
                                              granularity=cfg.granularity)
         same_v = bool(jnp.array_equal(f_snap, host.snapped))
@@ -288,8 +288,7 @@ def one_chip(serve, dispatch):
 
     mask_ops.fused_compute_reuse = counted
     try:
-        summary = run_serve(serve, dispatch,
-                            serve_argv(extra=("--reuse-stats",)), "ripple")
+        summary = run_serve(serve, dispatch, serve_argv(), "ripple")
     finally:
         mask_ops.fused_compute_reuse = fused
     plans = resolved_plans(dispatch, "ripple")
@@ -304,14 +303,13 @@ def one_chip(serve, dispatch):
         r = summary["reuse"].get(str(rid), {})
         say(f"[ripple] request {rid} reuse, means over {STEPS} steps x "
             f"{LAYERS} layers of attention: savings "
-            f"{r.get('reuse_savings', 0.0):.4f}, structural "
-            f"{r.get('reuse_structural_savings', 0.0):.4f}, q snapped "
-            f"{r.get('reuse_q_snap_frac', 0.0):.4f}, k snapped "
-            f"{r.get('reuse_k_snap_frac', 0.0):.4f}; decision cache "
+            f"{r.get('paper_savings', 0.0):.4f}, kernel skipped "
+            f"{r.get('skipped', 0.0):.4f}, q snapped "
+            f"{r.get('q_snap', 0.0):.4f}, k snapped "
+            f"{r.get('k_snap', 0.0):.4f}; decision cache "
             f"{r.get('cache_hits', 0)} hits / "
             f"{r.get('cache_refreshes', 0)} refreshes")
-        check(r.get("reuse_q_snap_frac", 0.0) > 0
-              and r.get("reuse_k_snap_frac", 0.0) > 0,
+        check(r.get("q_snap", 0.0) > 0 and r.get("k_snap", 0.0) > 0,
               f"ripple request {rid} snapped nothing")
     say(f"[ripple] peak device memory {peak_memory()}")
     gc.collect()

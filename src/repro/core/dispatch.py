@@ -71,6 +71,7 @@ from repro.config.base import RippleConfig
 from repro.core.collapse import collapsed_attention
 from repro.core.policy import (ReuseDecision, ReusePolicy, RippleStats,
                                get_policy, list_policies, register_policy)
+from repro.core.savings import collapse_savings
 from repro.utils.platform import platform
 
 __all__ = [
@@ -566,7 +567,11 @@ def _decide_extra(plan: DispatchPlan, policy: ReusePolicy,
 
 def _execute_backend(d: ReuseDecision, v, scale, *, plan: DispatchPlan,
                      cfg: RippleConfig):
-    """Fig. 6 steps ③-④: run the planned backend on one decision."""
+    """Fig. 6 steps ③-④: run the planned backend on one decision.
+    Returns ``out``, or ``(out, skipped)`` from a backend that reports
+    the share of MXU work it skipped itself: the ripple kernel, from the
+    block flags it ran on (deriving them again would read q and k a
+    second time).  :func:`_backend_skipped` covers the others."""
     if plan.backend == "pallas":
         # Deferred import: kernels are optional at module-import time.
         from repro.kernels.ripple.ops import ripple_attention_pallas
@@ -574,7 +579,8 @@ def _execute_backend(d: ReuseDecision, v, scale, *, plan: DispatchPlan,
         return ripple_attention_pallas(d.q, d.k, v, bias=d.bias,
                                        window=cfg.window,
                                        block_q=plan.block_q,
-                                       block_k=plan.block_k)
+                                       block_k=plan.block_k,
+                                       return_skipped=True)
     if plan.backend == "sparse":
         from repro.kernels.sparse.ops import sparse_attention_pallas
 
@@ -587,6 +593,50 @@ def _execute_backend(d: ReuseDecision, v, scale, *, plan: DispatchPlan,
                                    window=cfg.window, scale=scale)
     # 'reference': dense attention on the decided operands
     return dense_attention(d.q, d.k, v, scale, d.bias)
+
+
+def _run_backend(d: ReuseDecision, v, scale, *, plan: DispatchPlan,
+                 cfg: RippleConfig):
+    """:func:`_execute_backend`, as ``(out, skipped)``."""
+    res = _execute_backend(d, v, scale, plan=plan, cfg=cfg)
+    if isinstance(res, tuple):
+        return res
+    return res, _backend_skipped(d, plan)
+
+
+def _backend_skipped(d: ReuseDecision, plan: DispatchPlan):
+    """The share of attention MXU work a backend that does not report it
+    skips on this decision: the sparse kernel's skipped tiles, the
+    collapse path's collapsed windows, and nothing for dense execution
+    on the decided operands."""
+    zero = jnp.zeros((), jnp.float32)
+    if plan.backend == "sparse":
+        from repro.kernels.sparse.ops import sparse_block_stats
+
+        return zero if d.block_map is None \
+            else sparse_block_stats(d.block_map)
+    if plan.backend == "collapse" and d.q_mask is not None \
+            and d.k_mask is not None:
+        return collapse_savings(d.q_mask, d.k_mask, d.window)
+    return zero
+
+
+def _call_stats(policy: ReusePolicy, d: ReuseDecision,
+                skipped) -> RippleStats:
+    """The policy's accounting of one decision, with the structural
+    savings the executed backend reported."""
+    return dataclasses.replace(policy.stats(d), structural_savings=skipped)
+
+
+def _shard_mean(stats: RippleStats, mesh: Mesh) -> RippleStats:
+    """Inside shard_map: the mean of every shard's stats — one ``psum``
+    of the four per-shard values and a shard count.  Shards are equal
+    slices of (batch, heads), so this is the unsharded mean."""
+    vec = jnp.stack([jnp.asarray(x, jnp.float32) for x in (
+        stats.savings, stats.structural_savings, stats.q_snap_frac,
+        stats.k_snap_frac, 1.0)])
+    vec = jax.lax.psum(vec, tuple(mesh.axis_names))
+    return RippleStats(*(vec[i] / vec[4] for i in range(4)))
 
 
 def _inject_attn_nan(out, step):
@@ -615,14 +665,15 @@ def _run_pipeline(q, k, v, thetas, scale, bias, *, plan: DispatchPlan,
                   policy: ReusePolicy):
     """Fig. 6 steps ①-④ for one resolved plan: the policy's decision
     (snap / mask), then the planned backend on it.  Returns
-    (out, ReuseDecision).  Shard-oblivious: runs identically on the full
+    (out, RippleStats).  Shard-oblivious: runs identically on the full
     operands or on one shard_map shard (decisions only look along t/x/y,
     DESIGN.md §10).
     """
     d = policy.decide(q, k, grid=grid, cfg=cfg, thetas=thetas, bias=bias,
                       grid_slice=grid_slice, fused=plan.fused_mask,
                       **_decide_extra(plan, policy, cfg))
-    return _execute_backend(d, v, scale, plan=plan, cfg=cfg), d
+    out, skipped = _run_backend(d, v, scale, plan=plan, cfg=cfg)
+    return out, _call_stats(policy, d, skipped)
 
 
 def _run_pipeline_cached(q, k, v, thetas, scale, *, plan: DispatchPlan,
@@ -637,7 +688,7 @@ def _run_pipeline_cached(q, k, v, thetas, scale, *, plan: DispatchPlan,
     scan-carriable.  The backend then executes once on the selected
     decision (the kernels are not duplicated into the branches).
     External bias must be None (the dispatcher gates this).  Returns
-    (out, decision, new_cache).
+    (out, RippleStats, new_cache).
     """
     from repro.core import decision_cache as dc
 
@@ -675,8 +726,8 @@ def _run_pipeline_cached(q, k, v, thetas, scale, *, plan: DispatchPlan,
             refresh = dc.refresh_due(step, cfg, stat, cached.ref_stat,
                                      total_steps)
         d, new_cache = jax.lax.cond(refresh, fresh, reuse, cached)
-    out = _inject_attn_nan(_execute_backend(d, v, scale, plan=plan,
-                                            cfg=cfg), step)
+    out, skipped = _run_backend(d, v, scale, plan=plan, cfg=cfg)
+    out = _inject_attn_nan(out, step)
     if cfg.sentinel:
         from repro.core import guardrail
 
@@ -685,7 +736,7 @@ def _run_pipeline_cached(q, k, v, thetas, scale, *, plan: DispatchPlan,
         # operands — it measures the full approximation error.
         new_cache = guardrail.attach_sentinel(new_cache, out, q, k, v,
                                               scale, step, cfg)
-    return out, d, new_cache
+    return out, _call_stats(policy, d, skipped), new_cache
 
 
 def _operand_spec(plan: DispatchPlan, ndim: int) -> P:
@@ -720,7 +771,8 @@ def _lead_spec(plan: DispatchPlan, ndim: int) -> P:
 def _sharded_pipeline(q, k, v, thetas, scale, *, plan: DispatchPlan,
                       mesh: Mesh, grid, cfg: RippleConfig, grid_slice,
                       policy: ReusePolicy, step=None, cached=None,
-                      want_cache: bool = False, total_steps=None):
+                      want_cache: bool = False, total_steps=None,
+                      with_stats: bool = False):
     """Run :func:`_run_pipeline` under shard_map over the plan's batch /
     head axes.  No collectives: the sharded axes never carry a reuse
     window (the policy contract — decisions look only along t/x/y), so
@@ -731,6 +783,10 @@ def _sharded_pipeline(q, k, v, thetas, scale, *, plan: DispatchPlan,
     leaf keeps the operands' leading dims, so each shard carries (and
     refreshes) exactly its own cache slice — drift on one shard
     refreshes that shard alone.  Returns (out, new_cache) then.
+
+    With ``with_stats`` each shard's :class:`RippleStats` are combined
+    by one ``psum`` (:func:`_shard_mean`) and appended to the return.
+    The ring path carries no counters: its stats are NaN.
     """
     from jax.experimental.shard_map import shard_map
 
@@ -738,12 +794,21 @@ def _sharded_pipeline(q, k, v, thetas, scale, *, plan: DispatchPlan,
     th_vec = jnp.stack([jnp.asarray(thetas[a], jnp.float32)
                         for a in ("t", "x", "y")])
     scale = jnp.asarray(scale, jnp.float32)
+    stat_spec = (RippleStats(P(), P(), P(), P()),) if with_stats else ()
+
+    def result(out, stats, *cache):
+        res = (out, *cache) + ((_shard_mean(stats, mesh),)
+                               if with_stats else ())
+        return res if len(res) > 1 else out
 
     if plan.seq_axis is not None:
         # Context-parallel ring attention (core.ring, DESIGN.md §14).
         # Deferred import: ring lazily imports dense_attention back.
         from repro.core import ring as ring_lib
 
+        nan = jnp.full((), jnp.nan, jnp.float32)
+        no_stats = ((RippleStats(nan, nan, nan, nan),) if with_stats
+                    else ())
         if not want_cache:
             def ring_body(qs, ks, vs, th, sc):
                 th_d = {"t": th[0], "x": th[1], "y": th[2]}
@@ -754,7 +819,8 @@ def _sharded_pipeline(q, k, v, thetas, scale, *, plan: DispatchPlan,
             fn = shard_map(ring_body, mesh=mesh,
                            in_specs=(spec, spec, spec, P(), P()),
                            out_specs=spec, check_rep=False)
-            return fn(q, k, v, th_vec, scale)
+            out = fn(q, k, v, th_vec, scale)
+            return (out, *no_stats) if with_stats else out
 
         rstep = jnp.asarray(step, jnp.int32)
         # Deterministic spec construction — no eval_shape: the ring body
@@ -776,19 +842,21 @@ def _sharded_pipeline(q, k, v, thetas, scale, *, plan: DispatchPlan,
                        out_specs=(spec, cache_specs), check_rep=False)
         args = (q, k, v, th_vec, scale, rstep) + (
             (cached,) if cached is not None else ())
-        return fn(*args)
+        return fn(*args) + no_stats
 
     if not want_cache:
         def body(qs, ks, vs, th, sc):
             th_d = {"t": th[0], "x": th[1], "y": th[2]}
-            out, _ = _run_pipeline(qs, ks, vs, th_d, sc, None, plan=plan,
-                                   grid=grid, cfg=cfg, grid_slice=grid_slice,
-                                   policy=policy)
-            return out
+            out, stats = _run_pipeline(qs, ks, vs, th_d, sc, None,
+                                       plan=plan, grid=grid, cfg=cfg,
+                                       grid_slice=grid_slice,
+                                       policy=policy)
+            return result(out, stats)
 
         fn = shard_map(body, mesh=mesh,
                        in_specs=(spec, spec, spec, P(), P()),
-                       out_specs=spec, check_rep=False)
+                       out_specs=(spec, *stat_spec) if with_stats else spec,
+                       check_rep=False)
         return fn(q, k, v, th_vec, scale)
 
     step = jnp.asarray(step, jnp.int32)
@@ -806,16 +874,17 @@ def _sharded_pipeline(q, k, v, thetas, scale, *, plan: DispatchPlan,
 
     def body(qs, ks, vs, th, sc, st, *cache):
         th_d = {"t": th[0], "x": th[1], "y": th[2]}
-        out, _, new_cache = _run_pipeline_cached(
+        out, stats, new_cache = _run_pipeline_cached(
             qs, ks, vs, th_d, sc, plan=plan, grid=grid, cfg=cfg,
             grid_slice=grid_slice, policy=policy, step=st,
             cached=cache[0] if cache else None, total_steps=total_steps)
-        return out, new_cache
+        return result(out, stats, new_cache)
 
     in_specs = (spec, spec, spec, P(), P(), P()) + (
         (cache_specs,) if cached is not None else ())
     fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=(spec, cache_specs), check_rep=False)
+                   out_specs=(spec, cache_specs, *stat_spec),
+                   check_rep=False)
     args = (q, k, v, th_vec, scale, step) + (
         (cached,) if cached is not None else ())
     return fn(*args)
@@ -898,34 +967,38 @@ def attention_dispatch(
 
     thetas = pol.thetas_for(cfg, step, total_steps, thetas)
 
-    # Sharded fast path: stats need global reductions and an external
-    # bias would need its own spec — both stay on the replicated path.
-    if (mesh is not None and plan.sharded and bias is None
-            and not with_stats):
+    # Sharded fast path: an external bias would need its own spec, so
+    # it stays on the replicated path.
+    if mesh is not None and plan.sharded and bias is None:
         res = _sharded_pipeline(q, k, v, thetas, scale, plan=plan,
                                 mesh=mesh, grid=grid, cfg=cfg,
                                 grid_slice=grid_slice, policy=pol,
                                 step=step, cached=cached_decision,
                                 want_cache=want_cache,
-                                total_steps=total_steps)
-        # The cached body injects faults inside shard_map; the plain
-        # sharded path returns the bare output, so inject here.
-        return res if want_cache else _inject_attn_nan(res, step)
+                                total_steps=total_steps,
+                                with_stats=with_stats)
+        if want_cache:
+            # The cached body injects faults inside shard_map.
+            return res
+        # The plain sharded path returns the bare output: inject here.
+        if with_stats:
+            return _inject_attn_nan(res[0], step), res[1]
+        return _inject_attn_nan(res, step)
 
     if want_cache:
-        out, decision, new_cache = _run_pipeline_cached(
+        out, stats, new_cache = _run_pipeline_cached(
             q, k, v, thetas, scale, plan=plan, grid=grid, cfg=cfg,
             grid_slice=grid_slice, policy=pol, step=step,
             cached=cached_decision, total_steps=total_steps)
         if with_stats:
-            return out, new_cache, pol.stats(decision)
+            return out, new_cache, stats
         return out, new_cache
 
-    out, decision = _run_pipeline(
+    out, stats = _run_pipeline(
         q, k, v, thetas, scale, bias, plan=plan, grid=grid, cfg=cfg,
         grid_slice=grid_slice, policy=pol)
     out = _inject_attn_nan(out, step)
 
     if with_stats:
-        return out, pol.stats(decision)
+        return out, stats
     return out

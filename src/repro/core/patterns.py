@@ -770,18 +770,6 @@ class StaticPatternPolicy(ReusePolicy):
             savings=jnp.asarray(1.0 - keep_np.mean(), jnp.float32),
             block_map=cached.block_map)
 
-    def stats(self, decision):
-        zero = jnp.zeros(())
-        if decision.block_map is not None:
-            from repro.kernels.sparse.ops import sparse_block_stats
-
-            structural = sparse_block_stats(decision.block_map)
-        else:
-            structural = zero
-        return RippleStats(savings=decision.savings,
-                           structural_savings=structural,
-                           q_snap_frac=zero, k_snap_frac=zero)
-
     # -- ring/seq-shard hook (core/ring.py) -----------------------------
 
     def ring_bias_rows(self, q, k, *, grid, cfg, row_offset, n_rows):
@@ -857,12 +845,14 @@ class RainFusionPolicy(RipplePolicy):
                fused=False, block_shape=None, want_plan=False):
         keep_np, dyn_np = self._routing(q, grid, grid_slice)
         active_axes = tuple(cfg.axes)
-        q_s, q_mask, q_src = snap_operand(q, cfg.snap_q, grid, thetas, cfg,
-                                          active_axes, grid_slice, fused,
-                                          want_src=want_plan)
-        k_s, k_mask, k_src = snap_operand(k, cfg.snap_k, grid, thetas, cfg,
-                                          active_axes, grid_slice, fused,
-                                          want_src=want_plan)
+        # Static heads rewrite the masks below: their stats read the
+        # masks, not the fused kernel's counts.
+        q_s, q_mask, q_src, _ = snap_operand(
+            q, cfg.snap_q, grid, thetas, cfg, active_axes, grid_slice,
+            fused, want_src=want_plan)
+        k_s, k_mask, k_src, _ = snap_operand(
+            k, cfg.snap_k, grid, thetas, cfg, active_axes, grid_slice,
+            fused, want_src=want_plan)
         if not dyn_np.all():
             # Static heads keep their original operands (their mask is
             # the whole decision); snapping applies to dynamic heads

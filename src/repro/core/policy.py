@@ -58,7 +58,7 @@ from repro.core.svg_mask import svg_logit_bias
 @dataclasses.dataclass
 class RippleStats:
     savings: jax.Array             # paper accounting (partial-score reuse)
-    structural_savings: jax.Array  # realized by the collapse path
+    structural_savings: jax.Array  # MXU work the executed backend skipped
     q_snap_frac: jax.Array
     k_snap_frac: jax.Array
 
@@ -92,6 +92,10 @@ class ReuseDecision:
     the cacheable half of an operand-rewriting decision: re-applying it
     to fresh operands is one ``take_along_axis`` gather.
 
+    ``q_frac`` / ``k_frac`` are the masks' per-channel snapped fractions
+    (..., d) where the fused Δ-check counted them; the stats then need
+    not read the masks (None: they are derived from the masks).
+
     Registered as a jax pytree (``active_axes`` / ``window`` are static
     metadata) so whole decisions can flow through ``lax.cond`` — the
     decision cache's refresh-vs-reuse branch point.
@@ -109,12 +113,14 @@ class ReuseDecision:
     window: int = 2  # collapse-window size the masks were computed with
     q_src: Optional[jax.Array] = None
     k_src: Optional[jax.Array] = None
+    q_frac: Optional[jax.Array] = None
+    k_frac: Optional[jax.Array] = None
 
 
 jax.tree_util.register_dataclass(
     ReuseDecision,
     data_fields=["q", "k", "thetas", "bias", "q_mask", "k_mask", "savings",
-                 "block_map", "q_src", "k_src"],
+                 "block_map", "q_src", "k_src", "q_frac", "k_frac"],
     meta_fields=["active_axes", "window"])
 
 
@@ -303,32 +309,26 @@ class ReusePolicy:
     # -- savings accounting ---------------------------------------------
 
     def stats(self, decision: ReuseDecision) -> RippleStats:
-        """RippleStats for ``with_stats=True`` callers."""
+        """RippleStats for ``with_stats=True`` callers: the paper's
+        savings and the q/k snap fractions.  What the executed backend
+        skipped is known only to the dispatcher, which replaces the
+        ``structural_savings`` of zero returned here."""
         zero = jnp.zeros(())
-        realized = None
-        if decision.block_map is not None:
-            # A block map means the sparse backend executed this
-            # decision: what's *realized* is its skipped-tile fraction,
-            # not the collapse-path accounting (which never ran).
-            from repro.kernels.sparse.ops import sparse_block_stats
-
-            realized = sparse_block_stats(decision.block_map)
         if decision.q_mask is None or decision.k_mask is None:
             s = decision.savings if decision.savings is not None else zero
-            return RippleStats(
-                savings=s,
-                structural_savings=realized if realized is not None else s,
-                q_snap_frac=zero, k_snap_frac=zero)
+            return RippleStats(savings=s, structural_savings=zero,
+                               q_snap_frac=zero, k_snap_frac=zero)
+        # Every figure from the per-channel fractions, so each mask is
+        # read once, or not at all where the fused Δ-check counted them.
+        fq, fk = decision.q_frac, decision.k_frac
+        if fq is None:
+            fq = savings_lib.snapped_fractions(decision.q_mask)
+        if fk is None:
+            fk = savings_lib.snapped_fractions(decision.k_mask)
         return RippleStats(
-            savings=savings_lib.partial_score_savings(
-                decision.q_mask, decision.k_mask),
-            structural_savings=(
-                realized if realized is not None
-                else savings_lib.collapse_savings(
-                    decision.q_mask, decision.k_mask, decision.window)),
-            q_snap_frac=jnp.mean(decision.q_mask.astype(jnp.float32)),
-            k_snap_frac=jnp.mean(decision.k_mask.astype(jnp.float32)),
-        )
+            savings=savings_lib.savings_from_fractions(fq, fk),
+            structural_savings=zero,
+            q_snap_frac=jnp.mean(fq), k_snap_frac=jnp.mean(fk))
 
 
 def _keep_block_map(keep: jax.Array,
@@ -354,21 +354,24 @@ def _snap_segment(seg, grid, thetas, cfg: RippleConfig, active_axes,
     plan asks for it and the shape qualifies, host pipeline otherwise.
     ``want_src`` forces the host pipeline (the fused kernel does not
     expose snap sources) and additionally returns the source map —
-    bitwise-equal outputs either way (the fused-mask parity contract)."""
+    bitwise-equal outputs either way (the fused-mask parity contract).
+    Returns ``(snapped, mask, src, counts)``: ``counts`` (..., d) the
+    snapped entries per channel, from the fused kernel only."""
     if use_fused and not want_src:
         from repro.kernels.reuse_mask.ops import (fused_compute_reuse,
                                                   fused_reuse_eligible)
         if fused_reuse_eligible(grid, window=cfg.window,
                                 granularity=cfg.granularity,
                                 axes=active_axes):
-            s, m = fused_compute_reuse(seg, grid, thetas, axes=active_axes,
-                                       granularity=cfg.granularity)
-            return s, m, None
+            s, m, counts = fused_compute_reuse(
+                seg, grid, thetas, axes=active_axes,
+                granularity=cfg.granularity)
+            return s, m, None, counts
     r = reuse_lib.compute_reuse(
         seg, grid, thetas, axes=active_axes, window=cfg.window,
         granularity=cfg.granularity, channel_groups=cfg.channel_groups,
         want_src=want_src)
-    return r.snapped, r.mask, r.src_idx
+    return r.snapped, r.mask, r.src_idx, None
 
 
 def snap_operand(x, do: bool, grid, thetas, cfg: RippleConfig, active_axes,
@@ -376,21 +379,27 @@ def snap_operand(x, do: bool, grid, thetas, cfg: RippleConfig, active_axes,
     """Snap one operand (or pass it through with an all-False mask when
     ``do`` is off).  ``grid_slice`` restricts snapping to the grid
     tokens of a mixed text+grid sequence.  Returns ``(snapped, mask,
-    src)`` where ``src`` is the segment-scoped snap-source map (None
-    unless ``want_src`` and ``do``)."""
+    src, frac)`` where ``src`` is the segment-scoped snap-source map
+    (None unless ``want_src`` and ``do``) and ``frac`` (..., d) the
+    per-channel snapped fraction over all tokens, where the fused kernel
+    counted it (None otherwise: derive it from the mask)."""
     if not do:
-        return x, jnp.zeros(x.shape, jnp.bool_), None
+        return x, jnp.zeros(x.shape, jnp.bool_), None, None
+    n_all = x.shape[-2]
     if grid_slice is None:
-        return _snap_segment(x, grid, thetas, cfg, active_axes, use_fused,
-                             want_src)
-    s, n = grid_slice
-    seg = jax.lax.slice_in_dim(x, s, s + n, axis=-2)
-    snapped_seg, mask_seg, src_seg = _snap_segment(
-        seg, grid, thetas, cfg, active_axes, use_fused, want_src)
-    snapped = jax.lax.dynamic_update_slice_in_dim(x, snapped_seg, s, axis=-2)
-    mask = jnp.zeros(x.shape, jnp.bool_)
-    mask = jax.lax.dynamic_update_slice_in_dim(mask, mask_seg, s, axis=-2)
-    return snapped, mask, src_seg
+        snapped, mask, src, counts = _snap_segment(
+            x, grid, thetas, cfg, active_axes, use_fused, want_src)
+    else:
+        s, n = grid_slice
+        seg = jax.lax.slice_in_dim(x, s, s + n, axis=-2)
+        snapped_seg, mask_seg, src, counts = _snap_segment(
+            seg, grid, thetas, cfg, active_axes, use_fused, want_src)
+        snapped = jax.lax.dynamic_update_slice_in_dim(x, snapped_seg, s,
+                                                      axis=-2)
+        mask = jnp.zeros(x.shape, jnp.bool_)
+        mask = jax.lax.dynamic_update_slice_in_dim(mask, mask_seg, s,
+                                                   axis=-2)
+    return snapped, mask, src, None if counts is None else counts / n_all
 
 
 def replay_snap(x, src, grid_slice, snaps_operands: bool):
@@ -460,12 +469,12 @@ class RipplePolicy(ReusePolicy):
     def decide(self, q, k, *, grid, cfg, thetas, bias=None, grid_slice=None,
                fused=False, block_shape=None, want_plan=False):
         active_axes = tuple(cfg.axes)
-        q_s, q_mask, q_src = snap_operand(q, cfg.snap_q, grid, thetas, cfg,
-                                          active_axes, grid_slice, fused,
-                                          want_src=want_plan)
-        k_s, k_mask, k_src = snap_operand(k, cfg.snap_k, grid, thetas, cfg,
-                                          active_axes, grid_slice, fused,
-                                          want_src=want_plan)
+        q_s, q_mask, q_src, q_frac = snap_operand(
+            q, cfg.snap_q, grid, thetas, cfg, active_axes, grid_slice,
+            fused, want_src=want_plan)
+        k_s, k_mask, k_src, k_frac = snap_operand(
+            k, cfg.snap_k, grid, thetas, cfg, active_axes, grid_slice,
+            fused, want_src=want_plan)
         block_map = None
         if cfg.svg_mask:
             keep, bias = svg_logit_bias(q_s, k_s, grid, grid_slice, bias)
@@ -475,7 +484,7 @@ class RipplePolicy(ReusePolicy):
             q_mask=q_mask, k_mask=k_mask,
             savings=savings_lib.partial_score_savings(q_mask, k_mask),
             block_map=block_map, window=cfg.window,
-            q_src=q_src, k_src=k_src)
+            q_src=q_src, k_src=k_src, q_frac=q_frac, k_frac=k_frac)
 
 
 class EqualMSEPolicy(RipplePolicy):
@@ -573,22 +582,6 @@ class SVGPolicy(ReusePolicy):
             q=q, k=k, thetas=thetas, active_axes=(), bias=bias,
             savings=1.0 - jnp.mean(keep.astype(jnp.float32)),
             block_map=_keep_block_map(keep, block_shape))
-
-    def stats(self, decision):
-        zero = jnp.zeros(())
-        # savings = skippable score fraction (mask density); structural
-        # = the tile fraction the block-sparse backend skips outright —
-        # 0 when no block map was planned (reference execution computes
-        # the full dense score matrix and only zeroes weights).
-        if decision.block_map is not None:
-            from repro.kernels.sparse.ops import sparse_block_stats
-
-            structural = sparse_block_stats(decision.block_map)
-        else:
-            structural = zero
-        return RippleStats(savings=decision.savings,
-                           structural_savings=structural,
-                           q_snap_frac=zero, k_snap_frac=zero)
 
 
 class DensePolicy(ReusePolicy):

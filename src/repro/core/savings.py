@@ -28,12 +28,22 @@ import jax
 import jax.numpy as jnp
 
 
-def partial_score_savings(q_mask: jax.Array, k_mask: jax.Array) -> jax.Array:
-    """Paper-faithful savings ratio from boolean snap masks (..., N, d)."""
-    fq = jnp.mean(q_mask.astype(jnp.float32), axis=-2)  # (..., d)
-    fk = jnp.mean(k_mask.astype(jnp.float32), axis=-2)
+def snapped_fractions(mask: jax.Array) -> jax.Array:
+    """Per-channel snapped fraction (..., d) of a boolean snap mask
+    (..., N, d): the ``fq_c`` / ``fk_c`` above."""
+    return jnp.mean(mask.astype(jnp.float32), axis=-2)
+
+
+def savings_from_fractions(fq: jax.Array, fk: jax.Array) -> jax.Array:
+    """:func:`partial_score_savings` from per-channel snapped fractions."""
     computed = jnp.mean((1.0 - fq) * (1.0 - fk), axis=-1)
     return 1.0 - jnp.mean(computed)
+
+
+def partial_score_savings(q_mask: jax.Array, k_mask: jax.Array) -> jax.Array:
+    """Paper-faithful savings ratio from boolean snap masks (..., N, d)."""
+    return savings_from_fractions(snapped_fractions(q_mask),
+                                  snapped_fractions(k_mask))
 
 
 def pair_collapse_fractions(q_mask: jax.Array, k_mask: jax.Array,

@@ -13,6 +13,14 @@ model's per-layer stacked CachedDecision, e.g. from
 rides the denoising scan's carry, so the reuse decision is recomputed
 only on the ``reuse_every`` cadence (or drift), and the sampler returns
 ``(x, final_state)`` so callers can report cache hit rates.
+
+Per-step counters: with ``with_stats`` the model function appends a
+pytree of step counters to its return (``(out, stats)``, or ``(out,
+state, stats)`` with a decision state) and the sampler appends them,
+stacked along a leading step axis, to its own return.
+
+Each step's update is traced under ``jax.named_scope("sampler")``, so
+the profiler can tell it from the model's work.
 """
 
 from __future__ import annotations
@@ -37,6 +45,7 @@ def ddim_sample(
     step_offset=0,
     total_steps: Optional[int] = None,
     sentinel: bool = False,
+    with_stats: bool = False,
 ):
     """DDIM sampler. denoise_fn(x, t_int (B,), step_idx) -> eps.
 
@@ -78,35 +87,35 @@ def ddim_sample(
     def body(carry, si):
         x, rng, dstate, nf = carry
         t = ts[si]
-        t_prev = jnp.where(si + 1 < total, ts[jnp.minimum(si + 1,
-                                                          total - 1)], -1)
-        ab_t = alpha_bars[t]
-        ab_prev = jnp.where(t_prev >= 0, alpha_bars[jnp.maximum(t_prev, 0)], 1.0)
-        if dstate is None:
-            eps = denoise_fn(x, jnp.full((B,), t), si)
-        else:
-            eps, dstate = denoise_fn(x, jnp.full((B,), t), si, dstate)
-        x0 = (x - jnp.sqrt(1 - ab_t) * eps) / jnp.sqrt(ab_t)
-        sigma = eta * jnp.sqrt((1 - ab_prev) / (1 - ab_t)) * \
-            jnp.sqrt(1 - ab_t / ab_prev)
-        dir_xt = jnp.sqrt(jnp.maximum(1 - ab_prev - sigma ** 2, 0.0)) * eps
-        if rng is not None:
-            rng, sub = jax.random.split(rng)
-            noise = jax.random.normal(sub, x.shape, x.dtype)
-        else:
-            noise = jnp.zeros_like(x)
-        x = jnp.sqrt(ab_prev) * x0 + dir_xt + sigma * noise
-        if sentinel:
-            nf = nf + jnp.sum(~jnp.isfinite(x)).astype(jnp.int32)
-        return (x, rng, dstate, nf), None
+        eps, dstate, stats = _call(denoise_fn, x, jnp.full((B,), t), si,
+                                   dstate, with_stats)
+        with jax.named_scope("sampler"):
+            t_prev = jnp.where(si + 1 < total,
+                               ts[jnp.minimum(si + 1, total - 1)], -1)
+            ab_t = alpha_bars[t]
+            ab_prev = jnp.where(t_prev >= 0,
+                                alpha_bars[jnp.maximum(t_prev, 0)], 1.0)
+            x0 = (x - jnp.sqrt(1 - ab_t) * eps) / jnp.sqrt(ab_t)
+            sigma = eta * jnp.sqrt((1 - ab_prev) / (1 - ab_t)) * \
+                jnp.sqrt(1 - ab_t / ab_prev)
+            dir_xt = jnp.sqrt(jnp.maximum(1 - ab_prev - sigma ** 2,
+                                          0.0)) * eps
+            if rng is not None:
+                rng, sub = jax.random.split(rng)
+                noise = jax.random.normal(sub, x.shape, x.dtype)
+            else:
+                noise = jnp.zeros_like(x)
+            x = jnp.sqrt(ab_prev) * x0 + dir_xt + sigma * noise
+            if sentinel:
+                nf = nf + jnp.sum(~jnp.isfinite(x)).astype(jnp.int32)
+        return (x, rng, dstate, nf), stats
 
-    (x, _, dstate, nf), _ = jax.lax.scan(
+    (x, _, dstate, nf), stats = jax.lax.scan(
         body, (x_T, rng if rng is not None else jax.random.PRNGKey(0),
                decision_state, jnp.zeros((), jnp.int32)),
         jnp.arange(num_steps) + step_offset)
-    if decision_state is not None:
-        return (x, dstate, nf) if sentinel else (x, dstate)
-    return (x, nf) if sentinel else x
+    return _result(x, dstate, nf, stats, decision_state is not None,
+                   sentinel, with_stats)
 
 
 def euler_flow_sample(
@@ -119,6 +128,7 @@ def euler_flow_sample(
     step_offset=0,
     total_steps: Optional[int] = None,
     sentinel: bool = False,
+    with_stats: bool = False,
 ):
     """Euler ODE integration of rectified flow from t=1 (noise) to t=0.
     denoise_fn(x, t_cont (B,), step_idx) -> velocity (noise - x0).
@@ -136,21 +146,39 @@ def euler_flow_sample(
     def body(carry, si):
         x, dstate, nf = carry
         t, t_next = ts[si], ts[si + 1]
-        if dstate is None:
-            v = denoise_fn(x, jnp.full((B,), t), si)
-        else:
-            v, dstate = denoise_fn(x, jnp.full((B,), t), si, dstate)
-        x = x + (t_next - t) * v
-        if sentinel:
-            nf = nf + jnp.sum(~jnp.isfinite(x)).astype(jnp.int32)
-        return (x, dstate, nf), None
+        v, dstate, stats = _call(denoise_fn, x, jnp.full((B,), t), si,
+                                 dstate, with_stats)
+        with jax.named_scope("sampler"):
+            x = x + (t_next - t) * v
+            if sentinel:
+                nf = nf + jnp.sum(~jnp.isfinite(x)).astype(jnp.int32)
+        return (x, dstate, nf), stats
 
-    (x, dstate, nf), _ = jax.lax.scan(
+    (x, dstate, nf), stats = jax.lax.scan(
         body, (x_T, decision_state, jnp.zeros((), jnp.int32)),
         jnp.arange(num_steps) + step_offset)
-    if decision_state is not None:
-        return (x, dstate, nf) if sentinel else (x, dstate)
-    return (x, nf) if sentinel else x
+    return _result(x, dstate, nf, stats, decision_state is not None,
+                   sentinel, with_stats)
+
+
+def _call(denoise_fn, x, t, si, dstate, with_stats):
+    """One model call under the sampler contract: ``(out, state,
+    stats)``, with None for what the call does not return."""
+    res = (denoise_fn(x, t, si) if dstate is None
+           else denoise_fn(x, t, si, dstate))
+    if dstate is None and not with_stats:
+        return res, None, None
+    res = list(res)
+    stats = res.pop() if with_stats else None
+    return res[0], (res[1] if dstate is not None else None), stats
+
+
+def _result(x, dstate, nf, stats, has_state, sentinel, with_stats):
+    """``x``, then the final decision state, the non-finite count and the
+    per-step stats, each where asked for."""
+    out = (x,) + ((dstate,) if has_state else ()) \
+        + ((nf,) if sentinel else ()) + ((stats,) if with_stats else ())
+    return out if len(out) > 1 else x
 
 
 def cfg_wrap(denoise_fn: Callable, guidance: float) -> Callable:

@@ -110,7 +110,10 @@ def fused_reuse_snap(x: jax.Array, thetas: jax.Array, *,
     """x: (..., N, d) grid tokens in (t, y, x) row-major order;
     thetas: (3,) f32 in (θt, θx, θy) order.  Returns (snapped, mask:bool)
     shaped like x — the fused equivalent of ``compute_reuse`` restricted
-    to its eligible shapes (see :func:`fused_reuse_eligible`).
+    to its eligible shapes (see :func:`fused_reuse_eligible`) — and
+    ``counts`` (..., d) f32, the snapped entries per channel, summed from
+    the kernel's int8 mask (one read of it; the bool mask is dead unless
+    a caller reads it).
     """
     if interpret is None:
         interpret = not on_tpu()
@@ -127,8 +130,10 @@ def fused_reuse_snap(x: jax.Array, thetas: jax.Array, *,
     snapped, mask = fused_reuse_kernel(
         x4, th, axes=axes, granularity=granularity, width=W,
         with_t=with_t, block=blk, interpret=interpret)
+    counts = jnp.sum(mask, axis=(1, 2), dtype=jnp.float32)   # (G, d)
+    counts = counts.reshape(R, T // TT, d).sum(axis=1).reshape(*lead, d)
     return (snapped.reshape(*lead, N, d),
-            mask.reshape(*lead, N, d).astype(jnp.bool_))
+            mask.reshape(*lead, N, d).astype(jnp.bool_), counts)
 
 
 def fused_compute_reuse(x: jax.Array, grid: Tuple[int, int, int],
@@ -138,8 +143,8 @@ def fused_compute_reuse(x: jax.Array, grid: Tuple[int, int, int],
                         interpret: bool | None = None):
     """Dict-theta convenience mirroring ``compute_reuse``'s signature.
 
-    Returns (snapped, mask).  Callers must have checked
-    :func:`fused_reuse_eligible` first.
+    Returns (snapped, mask, counts) as :func:`fused_reuse_snap`.  Callers
+    must have checked :func:`fused_reuse_eligible` first.
     """
     th = jnp.stack([jnp.asarray(thetas.get(a, 0.0), jnp.float32)
                     for a in ("t", "x", "y")])

@@ -5,9 +5,11 @@ collapse flags from value equality, pair-splits, pads to block multiples
 (padded K pairs attend to nothing via a flag channel), runs the kernel,
 and re-interleaves the two output halves.
 
-Also exports :func:`ripple_block_stats` so benchmarks can report the
-fraction of MXU work the kernel actually skipped (the *structural*
-savings, as opposed to the paper's partial-score accounting).
+With ``return_skipped`` the wrapper also returns the share of MXU work
+the kernel skipped, :func:`ripple_block_stats` of the very flags it ran
+on (the *structural* savings, as opposed to the paper's partial-score
+accounting); :func:`ripple_block_flags` gives those flags without
+running the kernel.
 """
 
 from __future__ import annotations
@@ -25,28 +27,16 @@ from repro.utils.platform import on_tpu
 _PAD_NEG = -1e9
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("window", "block_q", "block_k", "interpret"))
-def ripple_attention_pallas(q, k, v, *, bias: Optional[jax.Array] = None,
-                            window: int = 2, block_q: int = 128,
-                            block_k: int = 128,
-                            interpret: bool | None = None):
-    """q,k,v: (B, H, N, d) snapped operands -> (B, H, N, dv)."""
-    assert bias is None, "ripple kernel path does not take a bias"
-    assert window == 2, "kernel implements the paper's window-2 sweet spot"
-    if interpret is None:
-        interpret = not on_tpu()
+def _pair_operands(q, k, v, block_q: int, block_k: int):
+    """Pair-split (B·H, pairs, d) kernel operands, padded to block
+    multiples, and their per-block collapse flags.  Returns
+    ``(operands, (q_flags, k_flags), (block_q, block_k))``."""
     B, H, N, d = q.shape
     dv = v.shape[-1]
     assert N % 2 == 0, "pair-collapse needs an even token count"
-    scale = float(1.0 / (d ** 0.5))
-
-    qf = q.reshape(B * H, N, d)
-    kf = k.reshape(B * H, N, d)
-    vf = v.reshape(B * H, N, dv)
-    q_e, q_o = split_pairs(qf)
-    k_e, k_o = split_pairs(kf)
-    v_e, v_o = split_pairs(vf)
+    q_e, q_o = split_pairs(q.reshape(B * H, N, d))
+    k_e, k_o = split_pairs(k.reshape(B * H, N, d))
+    v_e, v_o = split_pairs(v.reshape(B * H, N, dv))
 
     P = N // 2
     bq = min(block_q, P)
@@ -73,36 +63,59 @@ def ripple_attention_pallas(q, k, v, *, bias: Optional[jax.Array] = None,
         k_e = jnp.concatenate([k_e, flag_k], axis=-1)
         k_o = jnp.concatenate([k_o, flag_k], axis=-1)
 
-    qflags = block_flags(q_e, q_o, bq)
-    kflags = block_flags(k_e, k_o, bk)
-
-    o_e, o_o = ripple_attention_kernel(
-        q_e, q_o, k_e, k_o, v_e, v_o, qflags, kflags,
-        scale=scale, block_q=bq, block_k=bk, interpret=interpret)
-    o = jnp.stack([o_e[:, :P], o_o[:, :P]], axis=2)  # (BH, P, 2, dv)
-    return o.reshape(B, H, N, dv)
+    flags = (block_flags(q_e, q_o, bq), block_flags(k_e, k_o, bk))
+    return (q_e, q_o, k_e, k_o, v_e, v_o), flags, (bq, bk)
 
 
-def ripple_block_stats(q, k, *, block_q: int = 128, block_k: int = 128):
-    """Fraction of MXU matmul work the kernel skips for these operands.
-
-    Per (q, k) block pair the dense cost is 8 block-matmuls; k-collapse
-    alone leaves 4 (scores s_ee/s_oe + AV even/odd → wait, see kernel:
-    collapsed-k does 1 score + 1 AV per row half), q-collapse halves the
-    row halves.  cost = (2 − qc) · (1 + 1 if kc else 2 + 2)/... computed
-    explicitly below; dense = 8.
-    """
+@functools.partial(jax.jit,
+                   static_argnames=("window", "block_q", "block_k",
+                                    "interpret", "return_skipped"))
+def ripple_attention_pallas(q, k, v, *, bias: Optional[jax.Array] = None,
+                            window: int = 2, block_q: int = 128,
+                            block_k: int = 128,
+                            interpret: bool | None = None,
+                            return_skipped: bool = False):
+    """q,k,v: (B, H, N, d) snapped operands -> (B, H, N, dv), or
+    ``(out, skipped)`` with ``return_skipped``: the share of MXU work the
+    kernel skipped, :func:`ripple_block_stats` of the flags it ran on."""
+    assert bias is None, "ripple kernel path does not take a bias"
+    assert window == 2, "kernel implements the paper's window-2 sweet spot"
+    if interpret is None:
+        interpret = not on_tpu()
     B, H, N, d = q.shape
-    qf2 = q.reshape(B * H, N, d)
-    kf2 = k.reshape(B * H, N, d)
-    q_e, q_o = split_pairs(qf2)
-    k_e, k_o = split_pairs(kf2)
+    dv = v.shape[-1]
+    scale = float(1.0 / (d ** 0.5))
+    operands, (qflags, kflags), (bq, bk) = _pair_operands(
+        q, k, v, block_q, block_k)
+    o_e, o_o = ripple_attention_kernel(
+        *operands, qflags, kflags,
+        scale=scale, block_q=bq, block_k=bk, interpret=interpret)
     P = N // 2
-    bq, bk = min(block_q, P), min(block_k, P)
-    qc = block_flags(q_e[:, : (P // bq) * bq], q_o[:, : (P // bq) * bq], bq)
-    kc = block_flags(k_e[:, : (P // bk) * bk], k_o[:, : (P // bk) * bk], bk)
-    # per (qi, ki): row halves computed = 2 - qc; matmuls per half = 2 if kc else 4
-    halves = (2.0 - qc.astype(jnp.float32))[:, :, None]          # (BH, nq, 1)
-    per_half = jnp.where(kc.astype(jnp.float32)[:, None, :] > 0, 2.0, 4.0)
-    cost = jnp.mean(halves * per_half) / 8.0
-    return 1.0 - cost
+    o = jnp.stack([o_e[:, :P], o_o[:, :P]], axis=2)  # (BH, P, 2, dv)
+    out = o.reshape(B, H, N, dv)
+    if return_skipped:
+        return out, ripple_block_stats(qflags, kflags)
+    return out
+
+
+def ripple_block_flags(q, k, *, block_q: int = 128, block_k: int = 128):
+    """The (q_flags, k_flags) the kernel would run on for these snapped
+    operands, without running it."""
+    _, flags, _ = _pair_operands(q, k, k, block_q, block_k)
+    return flags
+
+
+def ripple_block_stats(q_flags, k_flags):
+    """Share of the kernel's MXU matmul work skipped under these block
+    flags ((B·H, q blocks) and (B·H, k blocks), 1 = collapsed).
+
+    Cost model (``kernel.py``): a (q block, k block) pair runs each of
+    its two row halves (the even and odd rows of the pairs) through 2
+    score and 2 AV matmuls, 8 in all.  A collapsed K block leaves 1
+    score and 1 AV matmul per row half; a collapsed Q block skips the
+    odd half.  So a pair costs ``(2 − q_flag) · (4 − 2·k_flag)`` of 8,
+    and the skipped share is one minus the mean cost over 8.
+    """
+    halves = jnp.mean(2.0 - q_flags.astype(jnp.float32), axis=-1)
+    per_half = jnp.mean(4.0 - 2.0 * k_flags.astype(jnp.float32), axis=-1)
+    return 1.0 - jnp.mean(halves * per_half) / 8.0

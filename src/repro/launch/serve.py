@@ -36,10 +36,10 @@ from repro.core.policy import RippleStats, list_policies
 from repro.diffusion.sampler import ddim_sample, euler_flow_sample
 from repro.diffusion.schedule import DDPMSchedule
 from repro.launch.mesh import parse_mesh_spec
-from repro.launch.workloads import (_denoise_call, attention_plan,
-                                    latent_shape_for, mixed_gen_shapes,
-                                    mixed_request_stream, model_fns,
-                                    vdit_decision_state)
+from repro.launch.workloads import (STATS_FAMILIES, _denoise_call,
+                                    attention_plan, latent_shape_for,
+                                    mixed_gen_shapes, mixed_request_stream,
+                                    model_fns, vdit_decision_state)
 from repro.distributed.sharding import NULL_CTX
 from repro.models.params import init_params
 from repro.serving.engine import DiffusionEngine
@@ -51,11 +51,18 @@ from repro.utils.platform import place_compile_cache
 log = get_logger("launch.serve")
 
 
+def reuse_counters(stats: RippleStats) -> jax.Array:
+    """The engine's ``COUNTERS`` (steps, 4) from per-step RippleStats,
+    each the mean over the model's attention calls: one array, so the
+    engine fetches every counter in one transfer."""
+    return jnp.stack([stats.q_snap_frac, stats.k_snap_frac, stats.savings,
+                      stats.structural_savings], axis=-1)
+
+
 def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
-                  reuse_every=None, stream_every=None, sentinel=False,
-                  reuse_stats=False):
+                  reuse_every=None, stream_every=None, sentinel=False):
     """Returns sample_fn(noise, txt, rngs) -> latents (or ``(latents,
-    aux)`` with decision-cache telemetry) and the latent shape.
+    aux)`` with telemetry) and the latent shape.
     ``rngs`` is the engine's (B, 2) per-request key batch: the initial
     noise is built outside from the same keys, and conditioning
     randomness (DiT labels) is drawn per request via vmap — no request
@@ -83,14 +90,10 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
     ``aux["sentinel_drift"]``) — the counters the engine's degradation
     ladder trips on.
 
-    ``reuse_stats=True`` (vdit, monolithic sampler) sums every
-    attention call's :class:`~repro.core.policy.RippleStats` through the
-    scan carry and reports their means over all steps and layers as
-    ``aux["reuse_savings"]``, ``["reuse_structural_savings"]``,
-    ``["reuse_q_snap_frac"]`` and ``["reuse_k_snap_frac"]``: what the
-    reuse path actually did for the batch.  The stats need reductions
-    over all heads, so under a dispatch mesh they keep attention on the
-    replicated path.
+    vdit and mmdit samplers always report the engine's reuse
+    ``COUNTERS`` of every step they run: ``aux["counters"]`` is a
+    (steps, 4) array computed on the device beside the step, for the
+    engine to fetch with the latents (DESIGN.md §15.5).
 
     ``params`` enter every jitted program as an argument, never as a
     closed-over constant: at published widths the weights are gigabytes,
@@ -108,11 +111,9 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
                                              reuse_every=int(reuse_every)))
     m = arch.model
     fam = arch.family
-    if reuse_stats and (fam != "vdit" or stream_every):
-        raise ValueError("reuse_stats needs a monolithic vdit sampler")
+    with_stats = fam in STATS_FAMILIES
     steps = shape.steps or 50
     lat_shape = latent_shape_for(arch, shape)
-    ddpm = DDPMSchedule()
     from repro.core import decision_cache
 
     rip = arch.ripple
@@ -120,6 +121,8 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
         use_ripple and fam == "vdit"
         and (rip.reuse_every > 1 or rip.drift_tol > 0)
         and decision_cache.supports_cache(rip))
+    sampler = (euler_flow_sample if fam == "mmdit" else
+               functools.partial(ddim_sample, schedule=DDPMSchedule()))
 
     def make_cond(txt, rngs):
         if fam == "dit":
@@ -145,41 +148,36 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
             aux["sentinel_drift"] = dstate.probe_err.max()
         return aux
 
+    def sample(params, x, txt, rngs, step0, dstate, count):
+        """``count`` denoising steps from ``step0``: ``(x, dstate,
+        non-finite count, counters)``, None for what is not tracked."""
+        cond = make_cond(txt, rngs)
+
+        def denoise(x, t, step, *ds):
+            res = _denoise_call(
+                arch, params, x, t, cond, step, steps, NULL_CTX,
+                use_ripple=use_ripple, dstate=ds[0] if ds else None,
+                with_stats=with_stats)
+            if not (ds or with_stats):
+                return res.astype(x.dtype)
+            return (res[0].astype(x.dtype),) + tuple(res[1:])
+
+        res = sampler(denoise, x, num_steps=count, decision_state=dstate,
+                      step_offset=step0, total_steps=steps,
+                      sentinel=sentinel, with_stats=with_stats)
+        res = list(res) if isinstance(res, tuple) else [res]
+        x = res.pop(0)
+        dstate = res.pop(0) if dstate is not None else None
+        nf = res.pop(0) if sentinel else None
+        counters = reuse_counters(res.pop(0)) if with_stats else None
+        return x, dstate, nf, counters
+
     if stream_every:
         K = max(int(stream_every), 1)
 
         @functools.partial(jax.jit, static_argnames=("count",))
         def chunk_fn(params, x, txt, rngs, step0, dstate, *, count):
-            cond = make_cond(txt, rngs)
-            if thread_cache:
-                def denoise(x, t, step, ds):
-                    out, ds = _denoise_call(
-                        arch, params, x, t, cond, step, steps, NULL_CTX,
-                        use_ripple=use_ripple, dstate=ds)
-                    return out.astype(x.dtype), ds
-                out = ddim_sample(denoise, x, ddpm, count,
-                                  decision_state=dstate,
-                                  step_offset=step0, total_steps=steps,
-                                  sentinel=sentinel)
-                return out if sentinel else out + (None,)
-
-            def denoise(x, t, step):
-                return _denoise_call(
-                    arch, params, x, t, cond, step, steps, NULL_CTX,
-                    use_ripple=use_ripple).astype(x.dtype)
-
-            if fam == "mmdit":
-                out = euler_flow_sample(denoise, x, count,
-                                        step_offset=step0,
-                                        total_steps=steps,
-                                        sentinel=sentinel)
-            else:
-                out = ddim_sample(denoise, x, ddpm, count,
-                                  step_offset=step0, total_steps=steps,
-                                  sentinel=sentinel)
-            if sentinel:
-                return out[0], None, out[1]
-            return out, None, None
+            return sample(params, x, txt, rngs, step0, dstate, count)
 
         def sample_fn(noise, txt, rngs, resume=None):
             # Mid-flight resume (DESIGN.md §18): ``resume={"step": S,
@@ -206,9 +204,9 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
             nf_total = jnp.zeros((), jnp.int32)
             for s0 in range(start, steps, K):
                 count = min(K, steps - s0)
-                x, dstate, nf = chunk_fn(params, x, txt, rngs,
-                                         jnp.asarray(s0, jnp.int32),
-                                         dstate, count=count)
+                x, dstate, nf, counters = chunk_fn(
+                    params, x, txt, rngs, jnp.asarray(s0, jnp.int32),
+                    dstate, count=count)
                 aux = {}
                 if dstate is not None:
                     cache_aux(dstate, aux)
@@ -217,6 +215,8 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
                     # aux reports the whole trajectory.
                     nf_total = nf_total + nf
                     aux["latent_nonfinite"] = nf_total
+                if counters is not None:
+                    aux["counters"] = counters
                 # Chunk-boundary checkpoint state for the engine's
                 # store (§18): the step offset the *next* chunk would
                 # start from, plus the decision state that step needs.
@@ -227,59 +227,22 @@ def build_sampler(arch, shape, params, *, use_ripple=True, policy=None,
 
     @jax.jit
     def run(params, noise, txt, rngs):
-        cond = make_cond(txt, rngs)
-
-        if thread_cache or reuse_stats:
-            # The scan carries (decision cache or None, running sum of
-            # RippleStats or None).
-            def denoise(x, t, step, state):
-                ds, acc = state
-                out, *extra = _denoise_call(
-                    arch, params, x, t, cond, step, steps, NULL_CTX,
-                    use_ripple=use_ripple, dstate=ds,
-                    with_stats=reuse_stats)
-                if ds is not None:
-                    ds = extra.pop(0)
-                if reuse_stats:
-                    acc = jax.tree.map(jnp.add, acc, extra.pop(0))
-                return out.astype(x.dtype), (ds, acc)
-
-            state = (vdit_decision_state(arch, shape.img_res,
-                                         noise.shape[0])
-                     if thread_cache else None,
-                     RippleStats(*[jnp.zeros((), jnp.float32)] * 4)
-                     if reuse_stats else None)
-            out = ddim_sample(denoise, noise, ddpm, steps,
-                              decision_state=state, sentinel=sentinel)
-            lat, (final, acc) = out[0], out[1]
-            aux = cache_aux(final, {}) if thread_cache else {}
-            if reuse_stats:
-                for f in dataclasses.fields(acc):
-                    aux[f"reuse_{f.name}"] = getattr(acc, f.name) / steps
-            if sentinel:
-                aux["latent_nonfinite"] = out[2]
-            return lat, aux
-
-        def denoise(x, t, step):
-            return _denoise_call(
-                arch, params, x, t, cond, step, steps, NULL_CTX,
-                use_ripple=use_ripple).astype(x.dtype)
-
-        if fam == "mmdit":
-            out = euler_flow_sample(denoise, noise, steps,
-                                    sentinel=sentinel)
-        else:
-            out = ddim_sample(denoise, noise, ddpm, steps,
-                              sentinel=sentinel)
+        dstate = (vdit_decision_state(arch, shape.img_res, noise.shape[0])
+                  if thread_cache else None)
+        x, final, nf, counters = sample(params, noise, txt, rngs, 0,
+                                        dstate, steps)
+        aux = cache_aux(final, {}) if thread_cache else {}
         if sentinel:
-            return out[0], {"latent_nonfinite": out[1]}
-        return out
+            aux["latent_nonfinite"] = nf
+        if counters is not None:
+            aux["counters"] = counters
+        return (x, aux) if aux else x
 
     return functools.partial(run, params), lat_shape
 
 
 def make_sampler_factory(arch, shapes, params, *, use_ripple=True,
-                         mesh=None, sentinel=False, reuse_stats=False):
+                         mesh=None, sentinel=False):
     """(engine sampler_factory, plan_fn) over a set of generate cells,
     keyed by the engine's (latent_shape, steps, policy, reuse_every,
     stream_every) bucket identity.  The engine hands both callables the
@@ -297,7 +260,7 @@ def make_sampler_factory(arch, shapes, params, *, use_ripple=True,
         fn, _ = build_sampler(arch, sp, params, use_ripple=use_ripple,
                               policy=policy, reuse_every=reuse_every,
                               stream_every=stream_every,
-                              sentinel=sentinel, reuse_stats=reuse_stats)
+                              sentinel=sentinel)
         return fn
 
     def plan_fn(latent_shape, steps, policy=None):
@@ -422,11 +385,6 @@ def main(argv=None):
                          "moves more than TOL (relative) from the cached "
                          "decision's reference.  0 disables (default: "
                          "the arch config's ripple.drift_tol)")
-    ap.add_argument("--reuse-stats", action="store_true",
-                    help="report what the reuse path did per batch: mean "
-                         "savings and q/k snap fractions over every "
-                         "attention call (vdit, monolithic buckets, no "
-                         "--mesh: the stats reduce over all heads)")
     ap.add_argument("--attn-backend", default=None,
                     choices=("auto", "dense", "reference", "collapse",
                              "pallas", "sparse"),
@@ -513,8 +471,6 @@ def main(argv=None):
                  f"{list_policies()} (use --policy-module to register "
                  f"an out-of-tree policy first)")
 
-    if args.reuse_stats and (args.mesh or args.stream_every):
-        ap.error("--reuse-stats needs monolithic buckets and no --mesh")
     mesh = parse_mesh_spec(args.mesh) if args.mesh else None
     if mesh is not None:
         dispatch_lib.set_dispatch_mesh(mesh)
@@ -627,7 +583,7 @@ def main(argv=None):
     params = init_params(defs, jax.random.PRNGKey(args.seed))
     factory, plan_fn = make_sampler_factory(
         arch, shapes, params, use_ripple=not args.no_ripple, mesh=mesh,
-        sentinel=guardrail, reuse_stats=args.reuse_stats)
+        sentinel=guardrail)
 
     def make_engine():
         return DiffusionEngine(sampler_factory=factory,

@@ -240,22 +240,21 @@ def mha_attention(
     B, N, _ = x.shape
     kv_src = encoder_out if encoder_out is not None else x
     Nk = kv_src.shape[1]
-    q = jnp.einsum("bnd,dh->bnh", x, params["wq"].astype(dt))
-    k = jnp.einsum("bnd,dh->bnh", kv_src, params["wk"].astype(dt))
-    v = jnp.einsum("bnd,dh->bnh", kv_src, params["wv"].astype(dt))
-    q = q.reshape(B, N, n_heads, head_dim)
-    k = k.reshape(B, Nk, n_heads, head_dim)
-    v = v.reshape(B, Nk, n_heads, head_dim)
-    if "q_norm" in params:
-        q = rmsnorm(params["q_norm"], q)
-        k = rmsnorm(params["k_norm"], k)
-    if rope_cos is not None:
-        q = apply_rope_precomputed(q, rope_cos, rope_sin)
-        k = apply_rope_precomputed(k, rope_cos, rope_sin)
-    # (B, H, N, hd) layout for the ripple/core path
-    q = ctx.c(q.transpose(0, 2, 1, 3), ("batch", "heads", "attn_seq", None))
-    k = ctx.c(k.transpose(0, 2, 1, 3), ("batch", "heads", None, None))
-    v = ctx.c(v.transpose(0, 2, 1, 3), ("batch", "heads", None, None))
+    # Named scopes for the profiler: ``proj`` (q/k/v with their norms and
+    # RoPE, and the output projection), ``attn`` (the dispatch call).
+    with jax.named_scope("proj"):
+        q = jnp.einsum("bnd,dh->bnh", x, params["wq"].astype(dt))
+        k = jnp.einsum("bnd,dh->bnh", kv_src, params["wk"].astype(dt))
+        v = jnp.einsum("bnd,dh->bnh", kv_src, params["wv"].astype(dt))
+        q = q.reshape(B, N, n_heads, head_dim)
+        k = k.reshape(B, Nk, n_heads, head_dim)
+        v = v.reshape(B, Nk, n_heads, head_dim)
+        if "q_norm" in params:
+            q = rmsnorm(params["q_norm"], q)
+            k = rmsnorm(params["k_norm"], k)
+        if rope_cos is not None:
+            q = apply_rope_precomputed(q, rope_cos, rope_sin)
+            k = apply_rope_precomputed(k, rope_cos, rope_sin)
 
     # Cross-attention has no grid to snap: force the dense backend so
     # the dispatcher bypasses the reuse pipeline entirely.
@@ -264,14 +263,21 @@ def mha_attention(
     if want_cache and encoder_out is not None:
         raise ValueError("decision caching applies to grid self-attention "
                          "only, not cross-attention")
-    res = attention_dispatch(
-        q, k, v, grid=grid, cfg=ripple, step=step,
-        total_steps=total_steps, grid_slice=grid_slice,
-        backend=eff_backend, cached_decision=cached_decision,
-        return_decision=want_cache, with_stats=with_stats)
-    out, *extra = res if (want_cache or with_stats) else (res,)
+    with jax.named_scope("attn"):
+        # (B, H, N, hd) layout for the ripple/core path
+        q = ctx.c(q.transpose(0, 2, 1, 3),
+                  ("batch", "heads", "attn_seq", None))
+        k = ctx.c(k.transpose(0, 2, 1, 3), ("batch", "heads", None, None))
+        v = ctx.c(v.transpose(0, 2, 1, 3), ("batch", "heads", None, None))
+        res = attention_dispatch(
+            q, k, v, grid=grid, cfg=ripple, step=step,
+            total_steps=total_steps, grid_slice=grid_slice,
+            backend=eff_backend, cached_decision=cached_decision,
+            return_decision=want_cache, with_stats=with_stats)
+        out, *extra = res if (want_cache or with_stats) else (res,)
+        out = out.transpose(0, 2, 1, 3).reshape(B, N, n_heads * head_dim)
 
-    out = out.transpose(0, 2, 1, 3).reshape(B, N, n_heads * head_dim)
-    out = jnp.einsum("bnh,hd->bnd", out, params["wo"].astype(dt))
+    with jax.named_scope("proj"):
+        out = jnp.einsum("bnh,hd->bnd", out, params["wo"].astype(dt))
     out = ctx.c(out, ("batch", "seq", "embed"))
     return (out, *extra) if extra else out

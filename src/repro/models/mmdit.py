@@ -100,17 +100,26 @@ def _qkv(bp, x, n_heads, hd):
 
 
 def _joint_attention(q, k, v, rope_cos, rope_sin, grid, grid_slice, ripple,
-                     step, total_steps, ctx):
-    """q/k/v: (B, N, H, hd) already normed; returns (B, N, H*hd)."""
-    q = apply_rope_precomputed(q, rope_cos, rope_sin)
-    k = apply_rope_precomputed(k, rope_cos, rope_sin)
-    qT = ctx.c(q.transpose(0, 2, 1, 3), ("batch", "heads", "attn_seq", None))
-    kT = ctx.c(k.transpose(0, 2, 1, 3), ("batch", "heads", None, None))
-    vT = ctx.c(v.transpose(0, 2, 1, 3), ("batch", "heads", None, None))
-    out = attention_dispatch(qT, kT, vT, grid=grid, cfg=ripple, step=step,
-                             total_steps=total_steps, grid_slice=grid_slice)
-    B, H, N, hd = out.shape
-    return out.transpose(0, 2, 1, 3).reshape(B, N, H * hd)
+                     step, total_steps, ctx, with_stats=False):
+    """q/k/v: (B, N, H, hd) already normed; returns (B, N, H*hd), and
+    the call's :class:`~repro.core.policy.RippleStats` with
+    ``with_stats``."""
+    with jax.named_scope("proj"):
+        q = apply_rope_precomputed(q, rope_cos, rope_sin)
+        k = apply_rope_precomputed(k, rope_cos, rope_sin)
+    with jax.named_scope("attn"):
+        qT = ctx.c(q.transpose(0, 2, 1, 3),
+                   ("batch", "heads", "attn_seq", None))
+        kT = ctx.c(k.transpose(0, 2, 1, 3), ("batch", "heads", None, None))
+        vT = ctx.c(v.transpose(0, 2, 1, 3), ("batch", "heads", None, None))
+        res = attention_dispatch(qT, kT, vT, grid=grid, cfg=ripple,
+                                 step=step, total_steps=total_steps,
+                                 grid_slice=grid_slice,
+                                 with_stats=with_stats)
+        out, stats = res if with_stats else (res, None)
+        B, H, N, hd = out.shape
+        out = out.transpose(0, 2, 1, 3).reshape(B, N, H * hd)
+    return (out, stats) if with_stats else out
 
 
 def mmdit_apply(
@@ -127,7 +136,10 @@ def mmdit_apply(
     ctx: ShardCtx = NULL_CTX,
     compute_dtype=jnp.bfloat16,
     remat: bool = False,
+    with_stats: bool = False,
 ) -> jax.Array:
+    """Apply the MMDiT.  ``with_stats`` returns ``(out, RippleStats)``
+    whose fields are the means over every block's attention call."""
     dt = compute_dtype
     B, Hl, Wl, C = latents.shape
     p = cfg.patch
@@ -137,93 +149,129 @@ def mmdit_apply(
     n_img = h * w
     hd = cfg.d_model // cfg.num_heads
 
-    img = latents.astype(dt).reshape(B, h, p, w, p, C).transpose(0, 1, 3, 2, 4, 5)
-    img = img.reshape(B, n_img, p * p * C)
-    img = linear(params["img_in"], img)
-    txt_tok = linear(params["txt_in"], txt.astype(dt))
+    with jax.named_scope("embed"):
+        img = latents.astype(dt).reshape(B, h, p, w, p, C) \
+            .transpose(0, 1, 3, 2, 4, 5)
+        img = img.reshape(B, n_img, p * p * C)
+        img = linear(params["img_in"], img)
+        txt_tok = linear(params["txt_in"], txt.astype(dt))
 
-    temb = sincos_timestep_embed(t, 256).astype(dt)
-    c = linear(params["t_mlp2"], jax.nn.silu(linear(params["t_mlp1"], temb)))
-    c = jax.nn.silu(c + linear(params["vec_in"], vec.astype(dt)))
+        temb = sincos_timestep_embed(t, 256).astype(dt)
+        c = linear(params["t_mlp2"],
+                   jax.nn.silu(linear(params["t_mlp1"], temb)))
+        c = jax.nn.silu(c + linear(params["vec_in"], vec.astype(dt)))
 
-    cos_g, sin_g = rope_3d_angles(grid, cfg.axes_dim)
-    ang_t = (1 + jnp.arange(L))[:, None].astype(jnp.float32) * (
-        1.0 / (10000.0 ** (jnp.arange(cfg.axes_dim[0] // 2, dtype=jnp.float32)
-                           / (cfg.axes_dim[0] // 2))))
-    rest = jnp.zeros((L, (cfg.axes_dim[1] + cfg.axes_dim[2]) // 2))
-    rope_cos = jnp.concatenate(
-        [jnp.cos(jnp.concatenate([ang_t, rest], -1)), cos_g], axis=0)
-    rope_sin = jnp.concatenate(
-        [jnp.sin(jnp.concatenate([ang_t, rest], -1)), sin_g], axis=0)
+        cos_g, sin_g = rope_3d_angles(grid, cfg.axes_dim)
+        ang_t = (1 + jnp.arange(L))[:, None].astype(jnp.float32) * (
+            1.0 / (10000.0 ** (jnp.arange(cfg.axes_dim[0] // 2,
+                                          dtype=jnp.float32)
+                               / (cfg.axes_dim[0] // 2))))
+        rest = jnp.zeros((L, (cfg.axes_dim[1] + cfg.axes_dim[2]) // 2))
+        rope_cos = jnp.concatenate(
+            [jnp.cos(jnp.concatenate([ang_t, rest], -1)), cos_g], axis=0)
+        rope_sin = jnp.concatenate(
+            [jnp.sin(jnp.concatenate([ang_t, rest], -1)), sin_g], axis=0)
 
+    # Named scopes label the step's device work for the profiler: ``ada``
+    # (modulation, norms, gates), ``proj``, ``attn`` and ``mlp``.
     def mod6(bp, x_):
         m = linear(bp["mod"], jax.nn.silu(c))
         return jnp.split(m, 6, axis=-1)
 
     def stream_pre(bp, x_):
-        sh, sc, g, sh2, sc2, g2 = mod6(bp, x_)
-        h_ = layernorm({}, x_) * (1 + sc[:, None]) + sh[:, None]
+        with jax.named_scope("ada"):
+            sh, sc, g, sh2, sc2, g2 = mod6(bp, x_)
+            h_ = layernorm({}, x_) * (1 + sc[:, None]) + sh[:, None]
         return h_, (g, sh2, sc2, g2)
 
     def stream_post(bp, x_, attn_out, mods):
         g, sh2, sc2, g2 = mods
-        x_ = x_ + g[:, None] * jnp.einsum(
-            "bnh,hd->bnd", attn_out, bp["wo"].astype(dt))
-        h_ = layernorm({}, x_) * (1 + sc2[:, None]) + sh2[:, None]
-        m = jax.nn.gelu(jnp.einsum("bnd,df->bnf", h_, bp["mlp_in"].astype(dt))
-                        + bp["mlp_in_b"].astype(dt))
-        m = jnp.einsum("bnf,fd->bnd", m, bp["mlp_out"].astype(dt)) \
-            + bp["mlp_out_b"].astype(dt)
-        return ctx.c(x_ + g2[:, None] * m, ("batch", "seq", "embed"))
+        with jax.named_scope("proj"):
+            o = jnp.einsum("bnh,hd->bnd", attn_out, bp["wo"].astype(dt))
+        with jax.named_scope("ada"):
+            x_ = x_ + g[:, None] * o
+            h_ = layernorm({}, x_) * (1 + sc2[:, None]) + sh2[:, None]
+        with jax.named_scope("mlp"):
+            m = jax.nn.gelu(
+                jnp.einsum("bnd,df->bnf", h_, bp["mlp_in"].astype(dt))
+                + bp["mlp_in_b"].astype(dt))
+            m = jnp.einsum("bnf,fd->bnd", m, bp["mlp_out"].astype(dt)) \
+                + bp["mlp_out_b"].astype(dt)
+        with jax.named_scope("ada"):
+            x_ = x_ + g2[:, None] * m
+        return ctx.c(x_, ("batch", "seq", "embed"))
+
+    def attend(q, k, v):
+        res = _joint_attention(q, k, v, rope_cos, rope_sin, grid, (L, n_img),
+                               ripple, step, total_steps, ctx,
+                               with_stats=with_stats)
+        return res if with_stats else (res, None)
 
     def double_body(carry, bp):
         txt_x, img_x = carry
         ti, im = bp["txt"], bp["img"]
         th, tmods = stream_pre(ti, txt_x)
         ih, imods = stream_pre(im, img_x)
-        tq, tk, tv = _qkv(ti, th, cfg.num_heads, hd)
-        iq, ik, iv = _qkv(im, ih, cfg.num_heads, hd)
-        q = jnp.concatenate([tq, iq], axis=1)
-        k = jnp.concatenate([tk, ik], axis=1)
-        v = jnp.concatenate([tv, iv], axis=1)
-        out = _joint_attention(q, k, v, rope_cos, rope_sin, grid, (L, n_img),
-                               ripple, step, total_steps, ctx)
+        with jax.named_scope("proj"):
+            tq, tk, tv = _qkv(ti, th, cfg.num_heads, hd)
+            iq, ik, iv = _qkv(im, ih, cfg.num_heads, hd)
+        with jax.named_scope("attn"):
+            q = jnp.concatenate([tq, iq], axis=1)
+            k = jnp.concatenate([tk, ik], axis=1)
+            v = jnp.concatenate([tv, iv], axis=1)
+        out, stats = attend(q, k, v)
         txt_x = stream_post(ti, txt_x, out[:, :L], tmods)
         img_x = stream_post(im, img_x, out[:, L:], imods)
-        return (txt_x, img_x), None
+        return (txt_x, img_x), stats
 
     def single_body(x_, bp):
-        m = linear(bp["mod"], jax.nn.silu(c))
-        sh, sc, g = jnp.split(m, 3, axis=-1)
-        h_ = layernorm({}, x_) * (1 + sc[:, None]) + sh[:, None]
+        with jax.named_scope("ada"):
+            m = linear(bp["mod"], jax.nn.silu(c))
+            sh, sc, g = jnp.split(m, 3, axis=-1)
+            h_ = layernorm({}, x_) * (1 + sc[:, None]) + sh[:, None]
         F = int(cfg.d_model * cfg.mlp_ratio)
-        fused = jnp.einsum("bnd,dh->bnh", h_, bp["lin1"].astype(dt)) \
-            + bp["lin1_b"].astype(dt)
-        qkv, mlp_h = fused[..., :3 * cfg.d_model], fused[..., 3 * cfg.d_model:]
-        B_, N_ = h_.shape[:2]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = _rmsn(bp["q_norm"], q.reshape(B_, N_, cfg.num_heads, hd))
-        k = _rmsn(bp["k_norm"], k.reshape(B_, N_, cfg.num_heads, hd))
-        v = v.reshape(B_, N_, cfg.num_heads, hd)
-        attn = _joint_attention(q, k, v, rope_cos, rope_sin, grid, (L, n_img),
-                                ripple, step, total_steps, ctx)
-        both = jnp.concatenate([attn, jax.nn.gelu(mlp_h)], axis=-1)
-        out = jnp.einsum("bnh,hd->bnd", both, bp["lin2"].astype(dt)) \
-            + bp["lin2_b"].astype(dt)
-        return ctx.c(x_ + g[:, None] * out, ("batch", "seq", "embed")), None
+        # One matmul projects q/k/v and the MLP's hidden layer together,
+        # and one projects attention and MLP out: both count as ``mlp``.
+        with jax.named_scope("mlp"):
+            fused = jnp.einsum("bnd,dh->bnh", h_, bp["lin1"].astype(dt)) \
+                + bp["lin1_b"].astype(dt)
+        with jax.named_scope("proj"):
+            qkv = fused[..., :3 * cfg.d_model]
+            B_, N_ = h_.shape[:2]
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = _rmsn(bp["q_norm"], q.reshape(B_, N_, cfg.num_heads, hd))
+            k = _rmsn(bp["k_norm"], k.reshape(B_, N_, cfg.num_heads, hd))
+            v = v.reshape(B_, N_, cfg.num_heads, hd)
+        attn, stats = attend(q, k, v)
+        with jax.named_scope("mlp"):
+            mlp_h = fused[..., 3 * cfg.d_model:]
+            both = jnp.concatenate([attn, jax.nn.gelu(mlp_h)], axis=-1)
+            out = jnp.einsum("bnh,hd->bnd", both, bp["lin2"].astype(dt)) \
+                + bp["lin2_b"].astype(dt)
+        with jax.named_scope("ada"):
+            x_ = x_ + g[:, None] * out
+        return ctx.c(x_, ("batch", "seq", "embed")), stats
 
     if remat:
         double_body = jax.checkpoint(double_body)
         single_body = jax.checkpoint(single_body)
 
-    (txt_x, img_x), _ = scan_layers(double_body, (txt_tok, img),
-                                    params["double"])
+    (txt_x, img_x), d_stats = scan_layers(double_body, (txt_tok, img),
+                                          params["double"])
     x = jnp.concatenate([txt_x, img_x], axis=1)
-    x, _ = scan_layers(single_body, x, params["single"])
+    x, s_stats = scan_layers(single_body, x, params["single"])
     img_x = x[:, L:]
 
-    sh, sc = jnp.split(linear(params["final_mod"], jax.nn.silu(c)), 2, axis=-1)
-    img_x = layernorm({}, img_x) * (1 + sc[:, None]) + sh[:, None]
-    out = linear(params["final"], img_x)  # (B, n_img, p*p*C)
-    out = out.reshape(B, h, w, p, p, C).transpose(0, 1, 3, 2, 4, 5)
-    return out.reshape(B, Hl, Wl, C)
+    with jax.named_scope("final"):
+        sh, sc = jnp.split(linear(params["final_mod"], jax.nn.silu(c)), 2,
+                           axis=-1)
+        img_x = layernorm({}, img_x) * (1 + sc[:, None]) + sh[:, None]
+        out = linear(params["final"], img_x)  # (B, n_img, p*p*C)
+        out = out.reshape(B, h, w, p, p, C).transpose(0, 1, 3, 2, 4, 5)
+        out = out.reshape(B, Hl, Wl, C)
+    if with_stats:
+        # Mean over every block's attention call, double and single.
+        return out, jax.tree.map(
+            lambda a, b: jnp.mean(jnp.concatenate([a, b])),
+            d_stats, s_stats)
+    return out

@@ -109,35 +109,42 @@ def vdit_apply(
     n_img = tg * hg * wg
     L_txt = txt.shape[1]
 
-    img = patchify_3d(latents.astype(dt), cfg.t_patch, cfg.patch)
-    img = jnp.einsum("bnd,df->bnf", img, params["patch"]["w"].astype(dt)) \
-        + params["patch"]["b"].astype(dt)
-    txt_tok = linear(params["txt_proj"], txt.astype(dt))
-    x = jnp.concatenate([txt_tok, img], axis=1)  # text first, then grid
-    x = ctx.c(x, ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        img = patchify_3d(latents.astype(dt), cfg.t_patch, cfg.patch)
+        img = jnp.einsum("bnd,df->bnf", img,
+                         params["patch"]["w"].astype(dt)) \
+            + params["patch"]["b"].astype(dt)
+        txt_tok = linear(params["txt_proj"], txt.astype(dt))
+        x = jnp.concatenate([txt_tok, img], axis=1)  # text first, then grid
+        x = ctx.c(x, ("batch", "seq", "embed"))
 
-    temb = sincos_timestep_embed(t, 256).astype(dt)
-    c = jax.nn.silu(linear(params["t_mlp2"],
-                           jax.nn.silu(linear(params["t_mlp1"], temb))))
+        temb = sincos_timestep_embed(t, 256).astype(dt)
+        c = jax.nn.silu(linear(params["t_mlp2"],
+                               jax.nn.silu(linear(params["t_mlp1"], temb))))
 
+        # Factorized 3-D RoPE; text tokens sit at grid origin with a pure
+        # temporal index beyond the video range so they never alias a
+        # frame.
+        cos_g, sin_g = rope_3d_angles(grid, cfg.axes_dim)
+        txt_pos = tg + jnp.arange(L_txt)
+        ang_t = txt_pos[:, None].astype(jnp.float32) * (1.0 / (
+            10000.0 ** (jnp.arange(cfg.axes_dim[0] // 2, dtype=jnp.float32)
+                        / (cfg.axes_dim[0] // 2))))
+        ang_rest = jnp.zeros((L_txt,
+                              (cfg.axes_dim[1] + cfg.axes_dim[2]) // 2))
+        cos_t = jnp.cos(jnp.concatenate([ang_t, ang_rest], axis=-1))
+        sin_t = jnp.sin(jnp.concatenate([ang_t, ang_rest], axis=-1))
+        rope_cos = jnp.concatenate([cos_t, cos_g], axis=0)
+        rope_sin = jnp.concatenate([sin_t, sin_g], axis=0)
     hd = cfg.d_model // cfg.num_heads
-    # Factorized 3-D RoPE; text tokens sit at grid origin with a pure
-    # temporal index beyond the video range so they never alias a frame.
-    cos_g, sin_g = rope_3d_angles(grid, cfg.axes_dim)
-    txt_pos = tg + jnp.arange(L_txt)
-    ang_t = txt_pos[:, None].astype(jnp.float32) * \
-        (1.0 / (10000.0 ** (jnp.arange(cfg.axes_dim[0] // 2, dtype=jnp.float32)
-                            / (cfg.axes_dim[0] // 2))))
-    ang_rest = jnp.zeros((L_txt, (cfg.axes_dim[1] + cfg.axes_dim[2]) // 2))
-    cos_t = jnp.cos(jnp.concatenate([ang_t, ang_rest], axis=-1))
-    sin_t = jnp.sin(jnp.concatenate([ang_t, ang_rest], axis=-1))
-    rope_cos = jnp.concatenate([cos_t, cos_g], axis=0)
-    rope_sin = jnp.concatenate([sin_t, sin_g], axis=0)
 
     def block(x, bp, dcache):
-        ada = linear(bp["ada"], c)
-        sh1, sc1, g1, sh2, sc2, g2 = jnp.split(ada, 6, axis=-1)
-        h_ = layernorm({}, x) * (1 + sc1[:, None]) + sh1[:, None]
+        # Named scopes (``ada``, ``proj``/``attn`` inside mha_attention,
+        # ``mlp``) label the step's device work for the profiler.
+        with jax.named_scope("ada"):
+            ada = linear(bp["ada"], c)
+            sh1, sc1, g1, sh2, sc2, g2 = jnp.split(ada, 6, axis=-1)
+            h_ = layernorm({}, x) * (1 + sc1[:, None]) + sh1[:, None]
         res = mha_attention(
             bp["attn"], h_, n_heads=cfg.num_heads, head_dim=hd, grid=grid,
             ripple=ripple, step=step, total_steps=total_steps,
@@ -149,9 +156,13 @@ def vdit_apply(
         if dcache is not None:
             dcache = extra.pop(0)
         stats = extra.pop(0) if with_stats else None
-        x = x + g1[:, None] * attn
-        h_ = layernorm({}, x) * (1 + sc2[:, None]) + sh2[:, None]
-        x = x + g2[:, None] * mlp(bp["mlp"], h_)
+        with jax.named_scope("ada"):
+            x = x + g1[:, None] * attn
+            h_ = layernorm({}, x) * (1 + sc2[:, None]) + sh2[:, None]
+        with jax.named_scope("mlp"):
+            m = mlp(bp["mlp"], h_)
+        with jax.named_scope("ada"):
+            x = x + g2[:, None] * m
         return ctx.c(x, ("batch", "seq", "embed")), (dcache, stats)
 
     if decision_state is None:
@@ -167,10 +178,11 @@ def vdit_apply(
         body = jax.checkpoint(body)
     x, (new_state, stats) = scan_layers(body, x, xs)
 
-    sh, sc = jnp.split(linear(params["final_ada"], c), 2, axis=-1)
-    x = layernorm({}, x[:, L_txt:]) * (1 + sc[:, None]) + sh[:, None]
-    x = linear(params["final"], x)
-    out = unpatchify_3d(x, cfg.t_patch, cfg.patch, tg, hg, wg, C)
+    with jax.named_scope("final"):
+        sh, sc = jnp.split(linear(params["final_ada"], c), 2, axis=-1)
+        x = layernorm({}, x[:, L_txt:]) * (1 + sc[:, None]) + sh[:, None]
+        x = linear(params["final"], x)
+        out = unpatchify_3d(x, cfg.t_patch, cfg.patch, tg, hg, wg, C)
     res = (out,)
     if decision_state is not None:
         res += (new_state,)

@@ -43,6 +43,14 @@ uninterrupted run via the PR 7 ``step_offset``/``total_steps`` chunked
 contract.  Failover-marked errors are never journaled as finished, so
 a crashed replica's requests stay pending for warm restart.
 
+Tracing (DESIGN.md §15.5): the engine thread wraps each phase of a
+batch in a profiler span — ``engine.take_batch``, ``engine.assemble``,
+then per chunk ``engine.dispatch``, ``engine.wait``, ``engine.copy_out``
+and ``engine.publish`` (``engine.finish`` for a streamed batch's final
+delivery) — with the batch index, the step and, on ``engine.publish``,
+the step's reuse counters as span arguments.  Arguments are formatted
+only while a trace is recording.
+
 LMEngine: KV-cache prefill + decode loop (used by the decode_32k /
 long_500k shape cells and the LM serving example).
 """
@@ -73,6 +81,39 @@ log = get_logger("serve")
 # replica instead of surfacing them (§15.4, §17.4).  Substring-matched
 # because errors cross the engine/router seam as strings.
 FAILOVER_MARKERS = ("engine stopped", "watchdog")
+
+
+# The reuse counters a sampler may report per denoising step, as the
+# columns of ``aux["counters"]`` (steps, 4), each the mean over the
+# model's attention calls (DESIGN.md §15.5): the q/k snap fractions, the
+# paper's partial-score savings, and the share of attention MXU work the
+# executed kernel skipped.
+COUNTERS = ("q_snap", "k_snap", "paper_savings", "skipped")
+
+_Span = jax.profiler.TraceAnnotation
+
+
+def _tracing() -> bool:
+    """Is a profiler trace recording?  Span arguments are built and
+    formatted only then, so with the profiler off a span costs one
+    native call."""
+    return _Span.is_enabled()
+
+
+def _span(name: str, **args):
+    """A profiler span over one engine phase (DESIGN.md §15.5)."""
+    return _Span(name, **args) if _tracing() else _Span(name)
+
+
+def _means(chunks: List[Dict[str, np.ndarray]]) -> Dict[str, float]:
+    """Each reuse counter's mean over every step of these chunks' per-
+    step counters; one a path does not carry (NaN) is left out."""
+    out = {}
+    for name in chunks[0]:
+        v = np.concatenate([c[name] for c in chunks]).astype(np.float64)
+        if np.all(np.isfinite(v)):
+            out[name] = float(v.mean())
+    return out
 
 
 def is_failover_error(msg: object) -> bool:
@@ -219,9 +260,10 @@ class GenResult:
     # by the guardrail ladder when this result was produced (§17.2)?
     degraded: bool = False
     # Reuse telemetry of the batch that served this request, read from
-    # the sampler's aux: decision-cache hits/refreshes (§13) and, from
-    # samplers built with ``reuse_stats``, the mean savings and q/k
-    # snap fractions over every attention call.
+    # the sampler's aux: decision-cache hits/refreshes (§13) and the
+    # reuse counters (q/k snap fractions, the paper's savings, the share
+    # of attention work the kernel skipped), each averaged over every
+    # step and attention call (§15.5).
     reuse: Optional[Dict[str, float]] = None
 
 
@@ -353,6 +395,7 @@ class DiffusionEngine:
         # streaming chunks: rid -> [np latents per delivered chunk]
         self._partials: Dict[int, List[np.ndarray]] = {}
         self._batches_served = 0
+        self._batches_taken = 0  # the batch index the spans carry
         self.shed_count = 0
         self.deadlines_met = 0
         self.deadlines_missed = 0
@@ -733,12 +776,13 @@ class DiffusionEngine:
             return out[0], out[1]
         return out, None
 
-    def _read_aux(self, key: BucketKey,
-                  aux: Optional[dict]) -> Optional[Dict[str, float]]:
+    def _read_aux(self, key: BucketKey, aux: Optional[dict],
+                  counters: Optional[Dict[str, float]] = None
+                  ) -> Optional[Dict[str, float]]:
         """Log and return the batch's reuse telemetry: decision-cache
         hits/refreshes (DESIGN.md §13), so the amortization is
-        observable in serving, not just benches, and the
-        ``reuse_*`` stats of samplers built with ``reuse_stats``."""
+        observable in serving, not just benches, and the batch's mean
+        reuse ``counters`` (§15.5)."""
         if not aux:
             return None
         hits = int(jax.device_get(aux.get("cache_hits", 0)))
@@ -749,19 +793,34 @@ class DiffusionEngine:
                 "bucket %s decision cache: %d hits / %d refreshes "
                 "(hit rate %.2f)", key, hits, refr,
                 hits / max(hits + refr, 1))
-        stats = {k: float(jax.device_get(v)) for k, v in aux.items()
-                 if k.startswith("reuse_")}
-        if stats:
-            reuse.update(stats)
+        if counters:
+            reuse.update(counters)
             log.info("bucket %s reuse: %s", key,
-                     ", ".join(f"{k[6:]} {v:.4f}"
-                               for k, v in stats.items()))
+                     ", ".join(f"{k} {v:.4f}" for k, v in counters.items()))
         if "ring_elided_hops" in aux:
             # Context-parallel telemetry (DESIGN.md §14): ring hops the
             # block map let the seq shards skip.
             log.info("bucket %s ring: %d elided hop(s)", key,
                      int(jax.device_get(aux["ring_elided_hops"])))
         return reuse
+
+    @staticmethod
+    def _fetch(lat, aux: Optional[dict], bi: int, step: int):
+        """Wait for one chunk's latents (``engine.wait``), then copy them
+        and the chunk's reuse counters (``aux["counters"]``) to the host
+        in one ``device_get`` (``engine.copy_out``).  Returns
+        ``(latents, counters)``, the counters as name -> per-step array,
+        or None."""
+        with _span("engine.wait", batch=bi, step=step):
+            lat = jax.block_until_ready(lat)
+        with _span("engine.copy_out", batch=bi, step=step):
+            aux = aux if isinstance(aux, dict) else {}
+            lat, counters = jax.device_get((lat, aux.get("counters")))
+            lat = np.asarray(lat)
+        if counters is None:
+            return lat, None
+        counters = np.asarray(counters).reshape(-1, len(COUNTERS))
+        return lat, {n: counters[:, i] for i, n in enumerate(COUNTERS)}
 
     # -- guardrail / watchdog serve path (DESIGN.md §17) ----------------------
 
@@ -916,14 +975,17 @@ class DiffusionEngine:
 
     def _run_batch(self, key: BucketKey,
                    batch: List[Tuple[float, GenRequest]], pub: Dict,
-                   abandoned: threading.Event):
+                   abandoned: threading.Event, bi: int = -1):
         """Run one sampler invocation, optionally under the hang
         watchdog.  Returns ``(res, hung, budget)`` where ``res`` holds
-        ``lat``/``aux`` on success, ``err`` (the exception) on failure,
+        ``lat``/``aux`` on success (and ``counters``, the reuse counters'
+        means over every step), ``err`` (the exception) on failure,
         or ``sentinel`` (a trip reason) when a streamed chunk went
         non-finite — caught *before* publication, so subscribers never
-        see the bad frames."""
+        see the bad frames.  ``bi`` is the batch index the spans
+        carry."""
         res: Dict[str, Any] = {}
+        seen: List[Dict[str, np.ndarray]] = []  # each chunk's counters
 
         def work():
             try:
@@ -935,51 +997,77 @@ class DiffusionEngine:
                         return  # hung past the watchdog; batch is lost
                 fn = self._sampler(key)
                 shape = key[0]
-                txt = jnp.stack([jnp.asarray(r.txt) for _, r in batch])
-                rngs = jnp.stack([jax.random.PRNGKey(r.seed)
-                                  for _, r in batch])
-                resume = self._assemble_resume(key, batch, fn)
-                if resume is not None:
-                    # Mid-flight resume (§18): the checkpointed x_t
-                    # replaces the initial noise and the sampler starts
-                    # at the checkpoint's step offset with the cached
-                    # decision state — the remaining schedule slice is
-                    # bitwise-identical to the uninterrupted run.
-                    noise = resume.pop("x")
-                    out = fn(noise, txt, rngs, resume=resume)
-                else:
-                    noise = jax.vmap(
-                        lambda k: jax.random.normal(k, shape))(rngs)
+                step = key[8] if len(key) > 8 else 0
+                with _span("engine.assemble", batch=bi):
+                    txt = jnp.stack([jnp.asarray(r.txt) for _, r in batch])
+                    rngs = jnp.stack([jax.random.PRNGKey(r.seed)
+                                      for _, r in batch])
+                    resume = self._assemble_resume(key, batch, fn)
+                    if resume is not None:
+                        # Mid-flight resume (§18): the checkpointed x_t
+                        # replaces the initial noise and the sampler
+                        # starts at the checkpoint's step offset with the
+                        # cached decision state — the remaining schedule
+                        # slice is bitwise-identical to the uninterrupted
+                        # run.
+                        noise = resume.pop("x")
+                        kw = {"resume": resume}
+                    else:
+                        noise = jax.vmap(
+                            lambda k: jax.random.normal(k, shape))(rngs)
+                        kw = {}
+                with _span("engine.dispatch", batch=bi, step=step):
                     # The full (B, 2) key batch goes to the sampler —
                     # every request keeps its own randomness inside one
-                    # batch.
-                    out = fn(noise, txt, rngs)
-                if inspect.isgenerator(out):
+                    # batch.  A streaming sampler returns a generator,
+                    # whose first ``next`` enqueues the first chunk.
+                    out = fn(noise, txt, rngs, **kw)
+                    streamed = inspect.isgenerator(out)
+                    chunk = next(out, None) if streamed else None
+                if streamed:
                     # Streaming bucket (§15.3): each yielded chunk is
                     # published to stream() subscribers as it lands; the
                     # last chunk is the final latents.
                     lat = aux = None
-                    for ci, chunk in enumerate(out):
+                    ci = 0
+                    while chunk is not None:
                         lat, aux = self._split_out(chunk)
                         ck = None
                         if isinstance(aux, dict):
                             ck = aux.pop("__ckpt__", None)
-                        lat = np.asarray(jax.device_get(lat))
+                        lat, counters = self._fetch(lat, aux, bi, step)
                         if (self._ladder is not None
                                 and not np.all(np.isfinite(lat))):
                             res["sentinel"] = \
                                 f"non-finite streamed chunk {ci}"
                             return
-                        self._publish_chunk(batch, lat, pub, ci, abandoned)
-                        self._record_chunk(key, batch, lat, ck, ci,
-                                           abandoned)
+                        with _span("engine.publish", batch=bi,
+                                   step=step) as sp:
+                            if counters:
+                                seen.append(counters)
+                                if _tracing():
+                                    sp.set_metadata(**_means([counters]))
+                            self._publish_chunk(batch, lat, pub, ci,
+                                                abandoned)
+                            self._record_chunk(key, batch, lat, ck, ci,
+                                               abandoned)
+                        ci += 1
+                        step = ck["step"] if ck else step + (key[6] or 1)
+                        if ck and 0 < key[1] <= step:
+                            break  # the last chunk: nothing to enqueue
+                        with _span("engine.dispatch", batch=bi, step=step):
+                            chunk = next(out, None)
                     if lat is None:
                         raise RuntimeError(
                             "streaming sampler yielded nothing")
                 else:
                     lat, aux = self._split_out(out)
-                    lat = np.asarray(jax.device_get(lat))
+                    lat, counters = self._fetch(lat, aux, bi, step)
+                    if counters:
+                        seen.append(counters)
                 res["lat"], res["aux"] = lat, aux
+                if seen:
+                    res["counters"] = _means(seen)
             except Exception as e:  # noqa: BLE001 — fail the batch, not the engine
                 log.exception("bucket %s batch failed", key)
                 res["err"] = e
@@ -1078,18 +1166,20 @@ class DiffusionEngine:
                 if self._store is not None:
                     self._store.discard(r.request_id)
 
-    def _serve(self, key: BucketKey, batch: List[Tuple[float, GenRequest]]):
+    def _serve(self, key: BucketKey, batch: List[Tuple[float, GenRequest]],
+               bi: int = -1):
         pub: Dict[str, Dict] = {"ttff": {}, "count": {}}
-        self._serve_rec(key, batch, 0, pub, threading.Event())
+        self._serve_rec(key, batch, 0, pub, threading.Event(), bi)
 
     def _serve_rec(self, key: BucketKey,
                    batch: List[Tuple[float, GenRequest]], depth: int,
-                   pub: Dict, abandoned: threading.Event):
+                   pub: Dict, abandoned: threading.Event, bi: int = -1):
         """Serve one (sub-)batch with the full §17 escalation chain:
         sentinel trip -> degrade one ladder rung and re-serve; hang ->
         watchdog (replica down); transient error -> retry with backoff,
         then bisect so a single poison request is quarantined alone
-        while its batchmates succeed."""
+        while its batchmates succeed.  ``bi`` is the batch index the
+        spans carry."""
         t0 = time.time()
         base_pol = key[2]
         fam = self._family(key)
@@ -1102,7 +1192,7 @@ class DiffusionEngine:
                 if eff_pol != base_pol:
                     eff_key = self._rekey(key, eff_pol)
             res, hung, budget = self._run_batch(eff_key, batch, pub,
-                                                abandoned)
+                                                abandoned, bi)
             if hung:
                 self._trip_watchdog(eff_key, batch, budget, abandoned)
                 return
@@ -1127,15 +1217,26 @@ class DiffusionEngine:
                 self._ladder.record_clean(fam)
             if exc is None:
                 dt = time.time() - t0
-                reuse = self._read_aux(eff_key, res.get("aux"))
-                self.estimator.observe(key, dt)
-                if eff_key != key:
-                    self.estimator.observe(eff_key, dt)
-                self._publish_batch(
-                    key, batch, res["lat"], dt, pub, None,
-                    degraded=(self._ladder is not None
-                              and self._ladder.degraded(fam)),
-                    reuse=reuse)
+                counters = res.get("counters")
+                # A streamed batch published every chunk as it landed;
+                # its results are the finish.  A monolithic batch
+                # publishes here, once.
+                streamed = len(key) > 6 and key[6]
+                with _span("engine.finish" if streamed
+                           else "engine.publish", batch=bi,
+                           step=key[8] if len(key) > 8 else 0) as sp:
+                    if counters and not streamed and _tracing():
+                        sp.set_metadata(**counters)
+                    reuse = self._read_aux(eff_key, res.get("aux"),
+                                           counters)
+                    self.estimator.observe(key, dt)
+                    if eff_key != key:
+                        self.estimator.observe(eff_key, dt)
+                    self._publish_batch(
+                        key, batch, res["lat"], dt, pub, None,
+                        degraded=(self._ladder is not None
+                                  and self._ladder.degraded(fam)),
+                        reuse=reuse)
                 log.info("served bucket %s batch of %d in %.2fs%s", key,
                          len(batch), dt,
                          " (degraded)" if eff_key != key else "")
@@ -1157,9 +1258,9 @@ class DiffusionEngine:
                 log.warning("bucket %s: bisecting failed batch of %d to "
                             "isolate the poison request", key, len(batch))
                 self._serve_rec(key, batch[:mid], depth + 1, pub,
-                                abandoned)
+                                abandoned, bi)
                 self._serve_rec(key, batch[mid:], depth + 1, pub,
-                                abandoned)
+                                abandoned, bi)
                 return
             if depth > 0 and len(batch) == 1:
                 # Bisection bottomed out on one request: quarantine it —
@@ -1175,10 +1276,16 @@ class DiffusionEngine:
 
     def _loop(self):
         while True:
-            key, batch = self._take_batch()
+            bi = self._batches_taken
+            with _span("engine.take_batch", batch=bi) as sp:
+                key, batch = self._take_batch()
+                if batch and _tracing():
+                    sp.set_metadata(size=len(batch), queue_wait_ms=1e3 * (
+                        time.time() - min(t for t, _ in batch)))
             if key is None:
                 return  # stopped and drained
-            self._serve(key, batch)
+            self._batches_taken += 1
+            self._serve(key, batch, bi)
             fault = fault_lib.active_faults()
             if fault is not None:
                 fault.maybe_corrupt_artifact(self._batches_served)

@@ -140,16 +140,26 @@ class TestFusedMask:
         th = {a: jnp.asarray(0.6, jnp.float32) for a in ("t", "x", "y")}
         assert fused_reuse_eligible(grid, granularity=granularity)
         r = compute_reuse(x, grid, th, granularity=granularity)
-        s, m = fused_compute_reuse(x, grid, th, granularity=granularity)
+        s, m, _ = fused_compute_reuse(x, grid, th, granularity=granularity)
         np.testing.assert_array_equal(np.asarray(m), np.asarray(r.mask))
         np.testing.assert_array_equal(np.asarray(s), np.asarray(r.snapped))
+
+    def test_counts_are_the_mask_per_channel(self):
+        """The fused path's per-channel counts, which the reuse counters
+        read in place of the mask, sum the mask over its tokens."""
+        x = jax.random.normal(jax.random.PRNGKey(2), (2, 3, N, D))
+        th = {a: jnp.asarray(0.6, jnp.float32) for a in ("t", "x", "y")}
+        _, m, counts = fused_compute_reuse(x, GRID, th)
+        assert counts.shape == (2, 3, D)
+        np.testing.assert_array_equal(np.asarray(counts),
+                                      np.asarray(m).sum(axis=-2))
 
     def test_axis_priority_matches_host(self):
         x = jax.random.normal(jax.random.PRNGKey(1), (1, N, D))
         th = {a: jnp.asarray(0.9, jnp.float32) for a in ("t", "x", "y")}
         for axes in (("t", "x", "y"), ("y", "t", "x"), ("x",)):
             r = compute_reuse(x, GRID, th, axes=axes)
-            s, m = fused_compute_reuse(x, GRID, th, axes=axes)
+            s, m, _ = fused_compute_reuse(x, GRID, th, axes=axes)
             np.testing.assert_array_equal(np.asarray(s), np.asarray(r.snapped))
 
     def test_ineligible_shapes_fall_back(self):

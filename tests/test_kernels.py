@@ -13,7 +13,8 @@ from repro.kernels.flash.ops import flash_attention
 from repro.kernels.flash.ref import attention_ref
 from repro.kernels.reuse_mask.ops import reuse_snap
 from repro.kernels.reuse_mask.ref import reuse_snap_ref
-from repro.kernels.ripple.ops import ripple_attention_pallas, ripple_block_stats
+from repro.kernels.ripple.ops import (ripple_attention_pallas,
+                                      ripple_block_flags, ripple_block_stats)
 from repro.kernels.ripple.ref import ripple_attention_ref
 from repro.kernels.sparse.ops import (FULL, PARTIAL, SKIP,
                                       block_map_from_keep,
@@ -79,13 +80,14 @@ class TestRippleKernel:
     def test_structural_savings_reach_75_when_fully_collapsed(self):
         q = _snapped_operand(jax.random.PRNGKey(4), 1, 1, 512, 32, 1.0)
         k = _snapped_operand(jax.random.PRNGKey(5), 1, 1, 512, 32, 1.0)
-        s = float(ripple_block_stats(q, k, block_q=64, block_k=64))
+        s = float(ripple_block_stats(*ripple_block_flags(q, k, block_q=64,
+                                                          block_k=64)))
         assert abs(s - 0.75) < 1e-6
 
     def test_zero_savings_when_nothing_collapses(self):
         q = _snapped_operand(jax.random.PRNGKey(6), 1, 1, 512, 32, 0.0)
         k = _snapped_operand(jax.random.PRNGKey(7), 1, 1, 512, 32, 0.0)
-        assert float(ripple_block_stats(q, k)) == 0.0
+        assert float(ripple_block_stats(*ripple_block_flags(q, k))) == 0.0
 
     @settings(max_examples=8, deadline=None)
     @given(seed=st.integers(0, 1000))

@@ -126,7 +126,8 @@ def test_structural_savings_materialize_on_redundant_data():
     blocks (token-granularity snapping makes full pairs)."""
     q, k = _correlated_qk(11)
     cfg = RippleConfig(enabled=True, granularity="token",
-                       fixed_threshold=0.5, i_min=0, i_max=1)
+                       fixed_threshold=0.5, i_min=0, i_max=1,
+                       execution="collapse")
     out, stats = attention_dispatch(
         q, k, jax.random.normal(jax.random.PRNGKey(12), (1, 1, N, D)),
         grid=GRID, cfg=cfg, step=jnp.asarray(0), total_steps=10,

@@ -617,9 +617,10 @@ class TestLauncherExit:
 
 
 class TestReuseStats:
-    """``--reuse-stats``: each served request reports what the reuse
-    path did (mean savings and q/k snap fractions over its attention
-    calls) without changing its latents."""
+    """Every served vdit request reports what the reuse path did: the
+    reuse counters (q/k snap fractions, the paper's savings, the share of
+    attention work the kernel skipped), averaged over its steps and
+    attention calls, whatever the delivery or the mesh."""
 
     # Four steps: with a cadence of 2 the decision made at step 2 (past
     # the dense step 0) is re-applied at step 3.
@@ -631,30 +632,41 @@ class TestReuseStats:
     def _isolated(self, tmp_path, monkeypatch):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
 
+    @staticmethod
+    def _check(r):
+        assert 0 < r["q_snap"] <= 1
+        assert 0 < r["k_snap"] <= 1
+        assert 0 < r["paper_savings"] <= 1
+        assert r["skipped"] == 0.0  # the CPU runs no skipping kernel
+
     @pytest.mark.parametrize("reuse_every", [1, 2])
     def test_request_reports_snaps_and_keeps_latents(self, reuse_every):
+        """Monolithic and streamed delivery report the same counters and
+        the same latents."""
         from repro.launch import serve
 
         argv = self.ARGV + ["--reuse-every", str(reuse_every)]
-        plain = serve.main(argv)
-        stats = serve.main(argv + ["--reuse-stats"])
-        r = stats["reuse"]["0"]
-        assert 0 < r["reuse_q_snap_frac"] <= 1
-        assert 0 < r["reuse_k_snap_frac"] <= 1
-        assert 0 < r["reuse_savings"] <= 1
-        assert 0 <= r["reuse_structural_savings"] <= 1
+        mono = serve.main(argv)
+        streamed = serve.main(argv + ["--stream-every", "1"])
+        r, s = mono["reuse"]["0"], streamed["reuse"]["0"]
+        self._check(r)
         assert (r["cache_hits"] > 0) == (reuse_every > 1)
-        assert not any(k.startswith("reuse_")
-                       for k in plain["reuse"].get("0", {}))
-        np.testing.assert_array_equal(plain["latents"][0],
-                                      stats["latents"][0])
+        for name in ("q_snap", "k_snap", "paper_savings", "skipped"):
+            assert s[name] == pytest.approx(r[name], rel=1e-6)
+        np.testing.assert_array_equal(mono["latents"][0],
+                                      streamed["latents"][0])
 
     def test_refused_under_a_mesh(self):
+        """``--mesh`` no longer stops the report: a 1x1 mesh serve
+        reports the counters of an unmeshed one."""
         from repro.launch import serve
 
-        with pytest.raises(SystemExit) as e:
-            serve.main(self.ARGV + ["--reuse-stats", "--mesh", "1x1"])
-        assert e.value.code == 2
+        plain = serve.main(self.ARGV)
+        meshed = serve.main(self.ARGV + ["--mesh", "1x1"])
+        self._check(meshed["reuse"]["0"])
+        for name in ("q_snap", "k_snap", "paper_savings", "skipped"):
+            assert meshed["reuse"]["0"][name] == pytest.approx(
+                plain["reuse"]["0"][name], rel=1e-6)
 
 
 class TestCompileCachePlacement:
