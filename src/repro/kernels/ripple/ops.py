@@ -1,9 +1,14 @@
 """Jitted wrapper for the ripple (pair-collapse) attention kernel.
 
 Accepts standard (B, H, N, d) snapped operands, derives the per-block
-collapse flags from value equality, pair-splits, pads to block multiples
-(padded K pairs attend to nothing via a flag channel), runs the kernel,
-and re-interleaves the two output halves.
+collapse flags from value equality, pair-splits, pads the pair axis with
+zeros to a multiple of the compute tile (the kernel masks padded keys),
+runs the kernel, and re-interleaves the two output halves.
+
+Two sizes are kept apart (DESIGN.md §4): ``block_q``/``block_k`` are the
+granularity of the collapse flags, the dispatch plan's blocks; the
+compute tile a grid step covers is chosen here from the operands' shape
+by :func:`compute_tiles`, a multiple of the flag blocks.
 
 With ``return_skipped`` the wrapper also returns the share of MXU work
 the kernel skipped, :func:`ripple_block_stats` of the very flags it ran
@@ -24,47 +29,74 @@ from repro.kernels.ripple.kernel import ripple_attention_kernel
 from repro.kernels.ripple.ref import block_flags, split_pairs
 from repro.utils.platform import on_tpu
 
-_PAD_NEG = -1e9
+# Compute-tile rule, measured on a TPU v5e (DESIGN.md §4).  A grid step
+# costs about 1.9 µs plus 0.21 µs per 128×128-pair block of work, so its
+# fixed cost is worth _STEP_COST blocks; past _MAX_TILE pairs a side the
+# time per block stops falling and larger tiles only pad.
+_STEP_COST = 9.0
+_MAX_TILE = 768
+
+
+def compute_tiles(n_pairs: int, block_q: int, block_k: int):
+    """(tile_q, tile_k) pairs per grid step for ``n_pairs`` pairs and
+    these flag blocks: multiples of the flag blocks of at most
+    ``_MAX_TILE``, the pair that minimizes the modelled time, padded
+    work included — ``steps × (_STEP_COST + work per step)``."""
+
+    def candidates(block):
+        top = max(block, min(_MAX_TILE, -(-n_pairs // block) * block))
+        return range(block, top + 1, block)
+
+    def cost(tq, tk):
+        steps = -(-n_pairs // tq) * -(-n_pairs // tk)
+        return steps * (_STEP_COST + tq * tk / 128 ** 2), -tq * tk
+
+    return min(((tq, tk) for tq in candidates(block_q)
+                for tk in candidates(block_k)), key=lambda t: cost(*t))
+
+
+def _pad(x, target):
+    padw = target - x.shape[1]
+    return x if padw == 0 else jnp.pad(x, ((0, 0), (0, padw), (0, 0)))
 
 
 def _pair_operands(q, k, v, block_q: int, block_k: int):
-    """Pair-split (B·H, pairs, d) kernel operands, padded to block
-    multiples, and their per-block collapse flags.  Returns
-    ``(operands, (q_flags, k_flags), (block_q, block_k))``."""
+    """Pair-split (B·H, pairs, d) kernel operands, zero-padded to tile
+    multiples, and the collapse flags.  Returns ``(operands,
+    (q_tile_flags, k_flags), (q_flags, k_flags_real), (bk, tq, tk))``:
+    the kernel's flags — one per Q tile, one per ``bk``-pair K block
+    with padded blocks 0 — and the flags per flag block over the real
+    pairs, each Q block carrying its tile's flag (what the kernel
+    executes, for :func:`ripple_block_stats`)."""
     B, H, N, d = q.shape
     dv = v.shape[-1]
     assert N % 2 == 0, "pair-collapse needs an even token count"
-    q_e, q_o = split_pairs(q.reshape(B * H, N, d))
-    k_e, k_o = split_pairs(k.reshape(B * H, N, d))
-    v_e, v_o = split_pairs(v.reshape(B * H, N, dv))
-
     P = N // 2
     bq = min(block_q, P)
     bk = min(block_k, P)
-    Pq = -(-P // bq) * bq
-    Pk = -(-P // bk) * bk
+    tq, tk = compute_tiles(P, bq, bk)
+    Pq = -(-P // tq) * tq
+    Pk = -(-P // tk) * tk
 
-    def pad(x, target):
-        padw = target - x.shape[1]
-        if padw <= 0:
-            return x
-        return jnp.pad(x, ((0, 0), (0, padw), (0, 0)))
+    q_e, q_o = split_pairs(q.reshape(B * H, N, d))
+    k_e, k_o = split_pairs(k.reshape(B * H, N, d))
+    v_e, v_o = split_pairs(v.reshape(B * H, N, dv))
+    q_e, q_o = _pad(q_e, Pq), _pad(q_o, Pq)
+    k_e, k_o, v_e, v_o = (_pad(x, Pk) for x in (k_e, k_o, v_e, v_o))
 
-    q_e, q_o = pad(q_e, Pq), pad(q_o, Pq)
-    k_e, k_o, v_e, v_o = pad(k_e, Pk), pad(k_o, Pk), pad(v_e, Pk), pad(v_o, Pk)
-    if Pk != P or Pq != P:
-        # flag channel: queries project 1, padded keys project −1e9.
-        ones_q = jnp.ones((B * H, Pq, 1), q_e.dtype)
-        flag_k = jnp.zeros((B * H, Pk, 1), k_e.dtype)
-        kmask = (jnp.arange(Pk) >= P)[None, :, None]
-        flag_k = jnp.where(kmask, _PAD_NEG, flag_k)
-        q_e = jnp.concatenate([q_e, ones_q], axis=-1)
-        q_o = jnp.concatenate([q_o, ones_q], axis=-1)
-        k_e = jnp.concatenate([k_e, flag_k], axis=-1)
-        k_o = jnp.concatenate([k_o, flag_k], axis=-1)
-
-    flags = (block_flags(q_e, q_o, bq), block_flags(k_e, k_o, bk))
-    return (q_e, q_o, k_e, k_o, v_e, v_o), flags, (bq, bk)
+    # Padded pairs are zero in both halves, so they read as collapsed:
+    # a block's flag is that of its real pairs.
+    q_blocks = block_flags(q_e, q_o, bq)            # (BH, Pq/bq)
+    q_tiles = jnp.min(q_blocks.reshape(B * H, Pq // tq, tq // bq), axis=-1)
+    nq_real, nk_real = -(-P // bq), -(-P // bk)
+    q_exec = jnp.repeat(q_tiles, tq // bq, axis=1)[:, :nq_real]
+    k_blocks = block_flags(k_e, k_o, bk)            # (BH, Pk/bk)
+    k_real = k_blocks[:, :nk_real]
+    # Blocks of padding alone take the whole-tile update, not the
+    # per-block walk.
+    k_kernel = jnp.where(jnp.arange(Pk // bk) < nk_real, k_blocks, 0)
+    return ((q_e, q_o, k_e, k_o, v_e, v_o), (q_tiles, k_kernel),
+            (q_exec, k_real), (bk, tq, tk))
 
 
 @functools.partial(jax.jit,
@@ -84,24 +116,25 @@ def ripple_attention_pallas(q, k, v, *, bias: Optional[jax.Array] = None,
         interpret = not on_tpu()
     B, H, N, d = q.shape
     dv = v.shape[-1]
-    scale = float(1.0 / (d ** 0.5))
-    operands, (qflags, kflags), (bq, bk) = _pair_operands(
+    P = N // 2
+    operands, kernel_flags, exec_flags, (bk, tq, tk) = _pair_operands(
         q, k, v, block_q, block_k)
     o_e, o_o = ripple_attention_kernel(
-        *operands, qflags, kflags,
-        scale=scale, block_q=bq, block_k=bk, interpret=interpret)
-    P = N // 2
+        *operands, *kernel_flags,
+        scale=float(1.0 / (d ** 0.5)), n_pairs=P, tile_q=tq, tile_k=tk,
+        block_k=bk, interpret=interpret)
     o = jnp.stack([o_e[:, :P], o_o[:, :P]], axis=2)  # (BH, P, 2, dv)
     out = o.reshape(B, H, N, dv)
     if return_skipped:
-        return out, ripple_block_stats(qflags, kflags)
+        return out, ripple_block_stats(*exec_flags)
     return out
 
 
 def ripple_block_flags(q, k, *, block_q: int = 128, block_k: int = 128):
-    """The (q_flags, k_flags) the kernel would run on for these snapped
-    operands, without running it."""
-    _, flags, _ = _pair_operands(q, k, k, block_q, block_k)
+    """The (q_flags, k_flags) the kernel executes for these snapped
+    operands, one per flag block of real pairs, without running it: a Q
+    block carries its compute tile's flag."""
+    _, _, flags, _ = _pair_operands(q, k, k, block_q, block_k)
     return flags
 
 

@@ -15,6 +15,7 @@ running this file loads the TPU library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,12 +65,39 @@ def test_flash_attention_compiles(one_chip):
              one_chip, *[((B, H, N, D), jnp.bfloat16)] * 3)
 
 
-def test_ripple_attention_compiles(one_chip):
+def _kernel_operand_shapes(compiled):
+    """Operand shapes of each ``tpu_custom_call`` in a compiled program,
+    as its ``operand_layout_constraints`` list them."""
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return [re.findall(r"\w+\[([\d,]*)\]", re.search(
+        r"operand_layout_constraints=\{(.*?\})\}", line).group(1))
+        for line in calls]
+
+
+def _ripple_operands_stay_d_wide(shape, sharding):
     from repro.kernels.ripple.ops import ripple_attention_pallas
 
-    _compile(lambda q, k, v: ripple_attention_pallas(q, k, v,
-                                                     interpret=False),
-             one_chip, *[((B, H, N, D), jnp.bfloat16)] * 3)
+    compiled = _compile(lambda q, k, v: ripple_attention_pallas(
+        q, k, v, interpret=False), sharding, *[(shape, jnp.bfloat16)] * 3)
+    (operands,) = _kernel_operand_shapes(compiled)
+    # Two flag tables, then the six pair-split tensors: padding never
+    # adds a channel.
+    assert len(operands) == 8
+    for dims in operands[2:]:
+        assert dims.split(",")[-1] == str(shape[-1])
+
+
+def test_ripple_attention_compiles(one_chip):
+    _ripple_operands_stay_d_wide((B, H, N, D), one_chip)
+
+
+@pytest.mark.parametrize("shape", [
+    (4, 24, 4608, D),              # flux-dev at 1024², batch 4
+    (1, 24, 2 * (2304 + 67), D),   # a pair count no tile divides
+])
+def test_ripple_operands_keep_head_dim(one_chip, shape):
+    _ripple_operands_stay_d_wide(shape, one_chip)
 
 
 @pytest.mark.parametrize("dtype,grid,d,granularity", [

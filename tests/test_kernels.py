@@ -13,6 +13,7 @@ from repro.kernels.flash.ops import flash_attention
 from repro.kernels.flash.ref import attention_ref
 from repro.kernels.reuse_mask.ops import reuse_snap
 from repro.kernels.reuse_mask.ref import reuse_snap_ref
+from repro.kernels.ripple import ops as ripple_ops
 from repro.kernels.ripple.ops import (ripple_attention_pallas,
                                       ripple_block_flags, ripple_block_stats)
 from repro.kernels.ripple.ref import ripple_attention_ref
@@ -101,6 +102,91 @@ class TestRippleKernel:
         ref = ripple_attention_ref(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=3e-5)
+
+    # Flag layouts inside one compute tile: 1 = every pair of that flag
+    # block collapsed.  Tiles are forced, so each case holds its layout
+    # whatever size the tile rule would pick.
+    @pytest.mark.parametrize("N,block,tiles,q_pattern,k_pattern", [
+        (1024, 128, (512, 512), (0, 0, 0, 0), (1, 0, 1, 0)),   # mixed K tile
+        (1024, 128, (512, 512), (1, 0, 1, 1), (0, 0, 0, 0)),   # part-collapsed Q tile
+        (1024, 128, (512, 256), (1, 1, 1, 1), (0, 1, 1, 1)),   # Q tile skipped
+        (384, 64, (128, 128), (1, 0, 1), (0, 1, 1)),           # 3×64 pairs, 128 tiles
+        (384, 64, (128, 128), (0, 0, 0), (0, 0, 0)),
+        (320, 32, (64, 96), (1, 0, 1, 1, 0), (1, 1, 0, 1, 1)),  # a block of K padding
+    ])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_tile_layouts_match_snapped_oracle(self, monkeypatch, N, block,
+                                               tiles, q_pattern, k_pattern,
+                                               dtype):
+        monkeypatch.setattr(ripple_ops, "compute_tiles",
+                            lambda P, bq, bk: tiles)
+        q = _block_snapped_operand(jax.random.PRNGKey(11), N, 32, block,
+                                   q_pattern).astype(dtype)
+        k = _block_snapped_operand(jax.random.PRNGKey(12), N, 32, block,
+                                   k_pattern).astype(dtype)
+        v = jax.random.normal(jax.random.PRNGKey(13), (1, 2, N, 32), dtype)
+        out, skipped = ripple_attention_pallas.__wrapped__(
+            q, k, v, block_q=block, block_k=block, return_skipped=True)
+        ref = ripple_attention_ref(*(x.astype(jnp.float32) for x in (q, k, v)))
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), atol=_tol(dtype),
+                                   rtol=1e-2 if dtype == jnp.bfloat16 else 0)
+        flags = ripple_block_flags(q, k, block_q=block, block_k=block)
+        assert float(skipped) == float(ripple_block_stats(*flags))
+
+    @pytest.mark.parametrize("q_pattern,k_pattern,want", [
+        # One 512-pair tile of four 128-pair flag blocks.  A Q tile
+        # skips its odd rows only when every block in it is collapsed.
+        ((1, 1, 1, 0), (1, 0, 0, 0), 1 - 2 * 3.5 / 8),
+        ((1, 1, 1, 1), (1, 0, 0, 0), 1 - 1 * 3.5 / 8),
+        ((0, 0, 0, 0), (1, 1, 1, 1), 1 - 2 * 2.0 / 8),
+        ((1, 1, 1, 1), (1, 1, 1, 1), 0.75),
+    ])
+    def test_block_stats_count_the_executed_q_granularity(
+            self, monkeypatch, q_pattern, k_pattern, want):
+        monkeypatch.setattr(ripple_ops, "compute_tiles",
+                            lambda P, bq, bk: (512, 512))
+        q = _block_snapped_operand(jax.random.PRNGKey(14), 1024, 16, 128,
+                                   q_pattern)
+        k = _block_snapped_operand(jax.random.PRNGKey(15), 1024, 16, 128,
+                                   k_pattern)
+        q_flags, k_flags = ripple_block_flags(q, k)
+        raw_q = (np.asarray(q_pattern) if all(q_pattern)
+                 else np.zeros(len(q_pattern), int))
+        np.testing.assert_array_equal(np.asarray(q_flags)[0], raw_q)
+        np.testing.assert_array_equal(np.asarray(k_flags)[0], k_pattern)
+        assert float(ripple_block_stats(q_flags, k_flags)) == \
+            pytest.approx(want, abs=1e-6)
+
+    @pytest.mark.parametrize("N,block", [(256, 64), (384, 64), (1030, 128)])
+    def test_fully_collapsed_still_skips_75_with_padding(self, N, block):
+        q = _snapped_operand(jax.random.PRNGKey(16), 1, 1, N, 16, 1.0)
+        k = _snapped_operand(jax.random.PRNGKey(17), 1, 1, N, 16, 1.0)
+        s = float(ripple_block_stats(*ripple_block_flags(
+            q, k, block_q=block, block_k=block)))
+        assert abs(s - 0.75) < 1e-6
+
+    @pytest.mark.parametrize("n_pairs,block", [
+        (16512, 128), (2304, 128), (192, 64), (65, 128), (7, 128)])
+    def test_compute_tiles_are_flag_block_multiples(self, n_pairs, block):
+        tq, tk = ripple_ops.compute_tiles(n_pairs, min(block, n_pairs),
+                                          min(block, n_pairs))
+        for t in (tq, tk):
+            assert t % min(block, n_pairs) == 0
+            assert t < n_pairs + min(block, n_pairs)
+
+
+def _block_snapped_operand(key, N, d, block, pattern):
+    """(1, 2, N, d) operand whose pair-split flag blocks of ``block``
+    pairs follow ``pattern`` (1 = every pair collapsed, 0 = none); pairs
+    past the pattern's end are left uncollapsed."""
+    x = jax.random.normal(key, (1, 2, N, d))
+    e, o = x[..., 0::2, :], x[..., 1::2, :]
+    per_pair = np.zeros(N // 2, bool)
+    for i, f in enumerate(pattern):
+        per_pair[i * block:(i + 1) * block] = bool(f)
+    coll = jnp.asarray(per_pair)[None, None, :, None]
+    return jnp.stack([e, jnp.where(coll, e, o)], 3).reshape(1, 2, N, d)
 
 
 def _sparse_qkv(seed, B=1, H=2, N=256, D=32):
